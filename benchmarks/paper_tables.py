@@ -162,9 +162,8 @@ def fig05_roofline():
     cfg = nvsa.NVSAConfig()
     params = cnn.init(jax.random.PRNGKey(0), cfg.cnn)
     imgs = jnp.zeros((128, 32, 32))
-    from repro.compat import cost_analysis
     c_n = jax.jit(lambda im: cnn.apply(params, im, cfg.cnn)["query"]).lower(imgs).compile()
-    ca_n = cost_analysis(c_n)
+    ca_n = c_n.cost_analysis()
     cbs = fz.make_codebooks(jax.random.PRNGKey(1), cfg.factorizer)
     qs = jnp.zeros((128, 1024))
     # one unbind+similarity sweep (the symbolic inner loop, loop-free for XLA)
@@ -173,7 +172,7 @@ def fig05_roofline():
         ub = fz._unbind_all_but_one(q, est, cfg.factorizer)  # batched, no vmap
         return jnp.einsum("nfd,fmd->nfm", ub, cbs)
     c_s = jax.jit(sym_step).lower(qs).compile()
-    ca_s = cost_analysis(c_s)
+    ca_s = c_s.cost_analysis()
     ai_n = ca_n["flops"] / max(ca_n["bytes accessed"], 1)
     ai_s = ca_s["flops"] / max(ca_s["bytes accessed"], 1)
     ridge = hw.RTX2080TI.peak_flops / hw.RTX2080TI.mem_bw  # paper profiles 2080Ti

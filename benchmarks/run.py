@@ -9,15 +9,18 @@ import argparse
 import json
 import os
 import sys
+import traceback
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", nargs="*", default=None)
     args = ap.parse_args()
+    from repro.launch.programs import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (engine_serve, engine_sharded, factorizer_batch,
                             fault_recovery, kernels_micro, lm_serve,
                             paper_hardware, paper_tables, runtime_serve)
@@ -29,13 +32,13 @@ def main() -> None:
     # __main__ entry that writes BENCH_factorizer.json)
     if args.only and any("factorizer" in o for o in args.only):
         mods.insert(2, factorizer_batch)
-    rows = []
+    rows, failed = [], []
     for mod in mods:
         try:
             rows += mod.run()
-        except Exception as e:  # one env-sensitive suite must not kill the rest
-            print(f"warning: {mod.__name__} failed: {type(e).__name__}: {e}",
-                  file=sys.stderr)
+        except Exception:  # report, run the rest, and fail the run at the end
+            traceback.print_exc()
+            failed.append(mod.__name__)
     if args.only:
         rows = [r for r in rows if any(o in r["benchmark"] for o in args.only)]
     print("name,us_per_call,derived")
@@ -44,7 +47,11 @@ def main() -> None:
     os.makedirs("artifacts", exist_ok=True)
     with open("artifacts/bench.json", "w") as f:
         json.dump(rows, f, indent=1)
+    if failed:
+        print(f"failed: {', '.join(failed)}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

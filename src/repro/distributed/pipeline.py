@@ -17,8 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 
 def pipeline_apply(layer_fn, stage_params, x_microbatches, *, mesh, axis="pipe"):
     """Run microbatches through P pipeline stages.
@@ -60,9 +58,9 @@ def pipeline_apply(layer_fn, stage_params, x_microbatches, *, mesh, axis="pipe")
         outs_all = jax.lax.all_gather(outs, axis)  # [P, M, mb, ...]
         return outs_all[n_stages - 1]
 
-    f = compat.shard_map(stage_body, mesh=mesh,
-                         in_specs=(P(axis), P()), out_specs=P(),
-                         check_vma=False)
+    f = jax.shard_map(stage_body, mesh=mesh,
+                      in_specs=(P(axis), P()), out_specs=P(),
+                      check_vma=False)
     return f(stage_params, x_microbatches)
 
 

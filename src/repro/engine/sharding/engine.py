@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.cogsim import model as hw_model
 from repro.core import factorizer as fz
 from repro.core.quantization import QTensor
@@ -151,15 +150,15 @@ class ShardedEngine(Engine):
             return make_rs(cb_arg).decode(qs, s)
 
         res_spec = fz.FactorizerResult(*([P("data")] * 5))
-        _sweeps = jax.jit(compat.shard_map(
+        _sweeps = jax.jit(jax.shard_map(
             sweeps_body, mesh=mesh,
             in_specs=(cb_spec, P("data"), state_spec, P()),
             out_specs=(state_spec, P()), check_vma=False))
-        _refill = jax.jit(compat.shard_map(
+        _refill = jax.jit(jax.shard_map(
             refill_body, mesh=mesh,
             in_specs=(cb_spec, P("data"), state_spec, P(), P(), P()),
             out_specs=(P("data"), state_spec), check_vma=False))
-        _decode = jax.jit(compat.shard_map(
+        _decode = jax.jit(jax.shard_map(
             decode_body, mesh=mesh,
             in_specs=(cb_spec, P("data"), state_spec),
             out_specs=res_spec, check_vma=False))

@@ -1,3 +1,16 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the paper's compute hot spots (kernel + ops + ref each).
+
+:func:`resolve_interpret` is the one place that decides whether a kernel
+runs compiled or in Pallas interpret mode.
+"""
+from __future__ import annotations
+
+import jax
+
+
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """``None`` compiles on a TPU and interprets on every other backend (the
+    CPU test path); a bool forces the choice."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret

@@ -9,12 +9,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.circconv import kernel as _k
 from repro.kernels.circconv import ref as _ref
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def block_circconv(xb: jax.Array, yb: jax.Array) -> jax.Array:
@@ -30,9 +27,10 @@ def block_circconv(xb: jax.Array, yb: jax.Array) -> jax.Array:
     y2 = jnp.broadcast_to(yb, xb.shape).reshape(-1, L)
     n_rows = x2.shape[0]
     if n_rows == 1 and L >= 512:
-        out = _k.circconv_single_mxu(x2[0], y2[0], interpret=_interpret())[None]
+        out = _k.circconv_single_mxu(x2[0], y2[0],
+                                     interpret=resolve_interpret())[None]
     else:
-        out = _k.circconv_rows(x2, y2, interpret=_interpret())
+        out = _k.circconv_rows(x2, y2, interpret=resolve_interpret())
     return out.reshape(*lead, L)
 
 

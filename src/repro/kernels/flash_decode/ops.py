@@ -8,16 +8,9 @@ the same convention as the resonator ``FusedConfig``.
 """
 from __future__ import annotations
 
-import jax
-
+from repro.kernels import resolve_interpret
 from repro.kernels.flash_decode import kernel as _k
 from repro.kernels.flash_decode import ref as _ref
-
-
-def resolve_interpret(interpret: bool | None) -> bool:
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
 
 
 def flash_decode(q, pool: dict, table, kv_lens, *, use_flash: bool = True,

@@ -103,7 +103,7 @@ def _step_kernel(q_ref, prod_ref, est_ref, cb_ref, alpha_ref, new_est_ref,
 
 def _masked_step_kernel(q_ref, prod_ref, est_ref, cb_ref, mask_ref,
                         alpha_ref, new_est_ref, *, use_abs: bool):
-    """Mask-aware variant: ``mask_ref`` [1, M] (1.0 = valid row) rides in
+    """Mask-aware variant: ``mask_ref`` [1, 1, M] (1.0 = valid row) rides in
     VMEM next to the codebook.  Invalid rows are neutralised to ``-1e9``
     before the activation (so they can never win the argmax) and zeroed
     before the projection (so padded atoms never leak into the estimates) —
@@ -112,13 +112,13 @@ def _masked_step_kernel(q_ref, prod_ref, est_ref, cb_ref, mask_ref,
     prod = prod_ref[...].astype(jnp.float32)
     est_f = est_ref[...][0].astype(jnp.float32)
     X = cb_ref[...][0].astype(jnp.float32)
-    m = mask_ref[...][0].astype(jnp.float32)  # [M]
+    m = mask_ref[...][0].astype(jnp.float32)  # [1, M]
     u = q * prod * est_f
     alpha = jax.lax.dot_general(
         u, X, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
-    alpha = jnp.where(m[None, :] > 0, alpha, _NEG)  # neutralise pre-activation
-    w = (jnp.abs(alpha) if use_abs else alpha) * m[None, :]  # zero pre-project
+    alpha = jnp.where(m > 0, alpha, _NEG)  # neutralise pre-activation
+    w = (jnp.abs(alpha) if use_abs else alpha) * m  # zero pre-project
     proj = jnp.dot(w, X, preferred_element_type=jnp.float32)
     new_est_ref[...] = jnp.where(proj >= 0, 1.0, -1.0)[None].astype(
         new_est_ref.dtype)
@@ -138,13 +138,13 @@ def _local_step_kernel(q_ref, prod_ref, est_ref, cb_ref, mask_ref,
     prod = prod_ref[...].astype(jnp.float32)
     est_f = est_ref[...][0].astype(jnp.float32)
     X = cb_ref[...][0].astype(jnp.float32)  # [M_loc, D] local rows
-    m = mask_ref[...][0].astype(jnp.float32)  # [M_loc]
+    m = mask_ref[...][0].astype(jnp.float32)  # [1, M_loc]
     u = q * prod * est_f
     alpha = jax.lax.dot_general(
         u, X, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)  # [Tn, M_loc]
-    w = jnp.where(m[None, :] > 0, alpha, _NEG)
-    w = (jnp.abs(w) if use_abs else w) * m[None, :]
+    w = jnp.where(m > 0, alpha, _NEG)
+    w = (jnp.abs(w) if use_abs else w) * m
     proj_ref[...] = jnp.dot(w, X, preferred_element_type=jnp.float32)[None]
     alpha_ref[...] = alpha[None].astype(alpha_ref.dtype)  # raw: masked post-psum
 
@@ -189,7 +189,7 @@ def resonator_step_batch_masked(qs: jax.Array, est: jax.Array,
     (alpha [N, F, M] with invalid rows at -1e9, new_est [N, F, D])."""
     F, M, D = codebooks.shape
     qs, prod, est_t, tn, N, Np = _pad_rows(qs, est, tn)
-    mask = valid_mask.astype(jnp.float32)
+    mask = valid_mask.astype(jnp.float32)[:, None, :]  # [F, 1, M]
     alpha, new_est = pl.pallas_call(
         functools.partial(_masked_step_kernel, use_abs=activation == "abs"),
         grid=(F, Np // tn),
@@ -198,7 +198,10 @@ def resonator_step_batch_masked(qs: jax.Array, est: jax.Array,
             pl.BlockSpec((tn, D), lambda f, n: (n, 0)),
             pl.BlockSpec((1, tn, D), lambda f, n: (f, n, 0)),
             pl.BlockSpec((1, M, D), lambda f, n: (f, 0, 0)),
-            pl.BlockSpec((1, M), lambda f, n: (f, 0)),  # validity mask f
+            # validity mask f; the unit middle dim keeps the block's last
+            # two dims equal to the array's, as the TPU (8, 128) tiling rule
+            # requires of a (1, M) row of an (F, M) array
+            pl.BlockSpec((1, 1, M), lambda f, n: (f, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, tn, M), lambda f, n: (f, n, 0)),
@@ -234,7 +237,7 @@ def resonator_step_batch_local(qs: jax.Array, est: jax.Array,
     qs, prod, est_t, tn, N, Np = _pad_rows(qs, est, tn)
     if valid_mask_local is None:
         valid_mask_local = jnp.ones((F, M_loc), jnp.float32)
-    mask = valid_mask_local.astype(jnp.float32)
+    mask = valid_mask_local.astype(jnp.float32)[:, None, :]  # [F, 1, M_loc]
     alpha, proj = pl.pallas_call(
         functools.partial(_local_step_kernel, use_abs=activation == "abs"),
         grid=(F, Np // tn),
@@ -243,7 +246,7 @@ def resonator_step_batch_local(qs: jax.Array, est: jax.Array,
             pl.BlockSpec((tn, D), lambda f, n: (n, 0)),
             pl.BlockSpec((1, tn, D), lambda f, n: (f, n, 0)),
             pl.BlockSpec((1, M_loc, D), lambda f, n: (f, 0, 0)),
-            pl.BlockSpec((1, M_loc), lambda f, n: (f, 0)),
+            pl.BlockSpec((1, 1, M_loc), lambda f, n: (f, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, tn, M_loc), lambda f, n: (f, n, 0)),

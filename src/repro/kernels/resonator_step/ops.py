@@ -10,8 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 
-import jax
-
+from repro.kernels import resolve_interpret
 from repro.kernels.resonator_step import kernel as _k
 from repro.kernels.resonator_step import ref as _ref
 
@@ -28,11 +27,6 @@ class FusedConfig:
 
     tn: int = 128
     interpret: bool | None = None
-
-    def resolve_interpret(self) -> bool:
-        if self.interpret is None:
-            return jax.default_backend() != "tpu"
-        return self.interpret
 
 
 DEFAULT_FUSED = FusedConfig()
@@ -63,7 +57,8 @@ def fused_resonator_step_batch(qs, est, codebooks, activation: str = "identity",
     """
     f = _cfg(fused)
     return _k.resonator_step_batch(qs, est, codebooks, activation=activation,
-                                   tn=f.tn, interpret=f.resolve_interpret())
+                                   tn=f.tn,
+                                   interpret=resolve_interpret(f.interpret))
 
 
 def fused_resonator_step_batch_masked(qs, est, codebooks, valid_mask,
@@ -75,7 +70,8 @@ def fused_resonator_step_batch_masked(qs, est, codebooks, valid_mask,
     f = _cfg(fused)
     return _k.resonator_step_batch_masked(qs, est, codebooks, valid_mask,
                                           activation=activation, tn=f.tn,
-                                          interpret=f.resolve_interpret())
+                                          interpret=resolve_interpret(
+                                              f.interpret))
 
 
 def fused_resonator_step_batch_local(qs, est, cb_local, valid_mask_local=None,
@@ -87,7 +83,8 @@ def fused_resonator_step_batch_local(qs, est, cb_local, valid_mask_local=None,
     f = _cfg(fused)
     return _k.resonator_step_batch_local(qs, est, cb_local, valid_mask_local,
                                          activation=activation, tn=f.tn,
-                                         interpret=f.resolve_interpret())
+                                         interpret=resolve_interpret(
+                                             f.interpret))
 
 
 def fused_resonator_step(q, est, codebooks, activation: str = "identity",
@@ -99,7 +96,7 @@ def fused_resonator_step(q, est, codebooks, activation: str = "identity",
     """
     f = _cfg(fused)
     return _k.resonator_step(q, est, codebooks, activation=activation,
-                             interpret=f.resolve_interpret())
+                             interpret=resolve_interpret(f.interpret))
 
 
 resonator_step_ref = _ref.resonator_step_ref

@@ -4,6 +4,7 @@ from __future__ import annotations
 import jax
 
 from repro.core.quantization import QTensor
+from repro.kernels import resolve_interpret
 from repro.kernels.similarity import kernel as _k
 from repro.kernels.similarity import ref as _ref
 
@@ -14,7 +15,7 @@ def codebook_scores(q: jax.Array, codebook: QTensor) -> jax.Array:
     q2 = q.reshape(-1, q.shape[-1])
     out = _k.similarity_int8(
         q2, codebook.values, codebook.scale,
-        interpret=jax.default_backend() != "tpu",
+        interpret=resolve_interpret(),
     )
     return out.reshape(*lead, -1)
 

@@ -259,8 +259,7 @@ def lower_cell(arch_id: str, shape_name: str, multi_pod: bool,
             "temp_bytes": int(getattr(mem, "temp_size_in_bytes", 0)),
             "alias_bytes": int(getattr(mem, "alias_size_in_bytes", 0)),
         }
-        from repro.compat import cost_analysis
-        ca = cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         coll = R.collective_bytes(compiled.as_text())
         chips = mesh.devices.size
         n_params, n_active = T.count_params_cfg(cfg)

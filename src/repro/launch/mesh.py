@@ -6,8 +6,16 @@ jax device state (smoke tests see 1 device; only dryrun.py forces 512).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.compat import make_mesh
+
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with Auto axes: the stack places arrays by sharding
+    annotations that GSPMD propagates, not by explicit-sharding types (the
+    default axis type of ``jax.make_mesh``)."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

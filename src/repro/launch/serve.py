@@ -30,6 +30,7 @@ import numpy as np
 
 from repro import obs as obs_mod
 from repro.configs.registry import ARCHS
+from repro.launch.programs import enable_compile_cache
 from repro.lm import model as lm_model
 from repro.lm import sampling as lm_sampling
 from repro.lm.paging import BlockTablePool, PagedConfig, cdiv
@@ -74,6 +75,9 @@ class ServeEngine:
         self.prefill_dispatches = 0
         self.decode_dispatches = 0
         self.kv_bytes_touched = 0
+        # [slots, 1, vocab] fp32 logits of the latest decode step (None
+        # before the first): what layouts and kernels are compared on
+        self.last_logits = None
         if paged is not None:
             lm_model.check_paging_supported(cfg)
             nb = paged.resolve_num_blocks(batch_slots, max_len)
@@ -306,6 +310,7 @@ class ServeEngine:
         else:
             logits, self.cache = self._decode(self.params, self.cache, last,
                                               jnp.asarray(self.active))
+        self.last_logits = logits
         self.decode_dispatches += 1
         kv_bytes = self._kv_step_bytes()
         self.kv_bytes_touched += kv_bytes
@@ -380,6 +385,7 @@ def main():
         handler.setFormatter(logging.Formatter("%(message)s"))
         log.addHandler(handler)
         log.setLevel(logging.INFO)
+    enable_compile_cache()
     spec = ARCHS[args.arch]
     cfg = spec.smoke() if args.smoke else spec.full()
     key = jax.random.PRNGKey(0)

@@ -27,7 +27,6 @@ from __future__ import annotations
 
 import dataclasses
 
-import jax
 import numpy as np
 
 
@@ -68,11 +67,6 @@ class PagedConfig:
             v = getattr(self, name)
             if v is not None and v < 1:
                 raise ValueError(f"{name} must be >= 1, got {v}")
-
-    def resolve_interpret(self) -> bool:
-        if self.interpret is None:
-            return jax.default_backend() != "tpu"
-        return self.interpret
 
     def resolve_num_blocks(self, slots: int, max_len: int) -> int:
         if self.num_blocks is not None:

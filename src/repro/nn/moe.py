@@ -127,11 +127,10 @@ def _batch_manual(fn, n_out: int):
         return fn
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     spec = P(axes if len(axes) > 1 else axes[0])
-    return compat.shard_map(fn, mesh=mesh, in_specs=spec,
-                            out_specs=(spec,) * n_out if n_out > 1 else spec,
-                            axis_names=set(axes), check_vma=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=spec,
+                         out_specs=(spec,) * n_out if n_out > 1 else spec,
+                         axis_names=set(axes), check_vma=False)
 
 
 def moe(p, x: jax.Array, cfg: MoEConfig) -> tuple:

@@ -122,8 +122,10 @@ def _init_block(key, kind: str, cfg: ModelConfig):
     return p, lg
 
 
-def init(key: jax.Array, cfg: ModelConfig):
-    """Returns (params, logical). Blocks stacked across periods: leaf[P, ...]."""
+def _init_tree(key: jax.Array, cfg: ModelConfig):
+    """(params, logical) as traced by :func:`init` and :func:`abstract_init`.
+    Blocks stacked across periods: leaf[P, ...], built by ``vmap`` over the
+    period keys rather than by stacking per-period copies."""
     params, logical = {}, {}
     key, k_emb, k_head = jax.random.split(key, 3)
     params["embed"] = (jax.random.normal(k_emb, (cfg.vocab, cfg.d_model))
@@ -136,21 +138,25 @@ def init(key: jax.Array, cfg: ModelConfig):
     norm_init = L.init_rmsnorm if cfg.norm == "rmsnorm" else L.init_layernorm
     params["final_ln"], logical["final_ln"] = norm_init(cfg.d_model)
 
-    blocks, blocks_lg = [], None
-    for pi in range(cfg.n_periods):
+    period_keys = []
+    for _ in range(cfg.n_periods):
         key, k = jax.random.split(key)
+        period_keys.append(k)
+    box = {}
+
+    def init_period(k):
         per, per_lg = [], []
-        for bi, kind in enumerate(cfg.block_pattern):
+        for kind in cfg.block_pattern:
             k, kb = jax.random.split(k)
             bp, blg = _init_block(kb, kind, cfg)
-            per.append(bp)
+            per.append(jax.tree.map(lambda x: x.astype(cfg.param_dtype), bp))
             per_lg.append(blg)
-        blocks.append(per)
-        blocks_lg = per_lg
-    # stack periods: leaf -> [P, ...]
-    params["blocks"] = jax.tree.map(lambda *xs: jnp.stack(xs).astype(cfg.param_dtype),
-                                    *blocks)
-    logical["blocks"] = jax.tree.map(lambda lgx: ("layers",) + lgx, blocks_lg,
+        box["logical"] = per_lg
+        return per
+
+    params["blocks"] = jax.vmap(init_period)(jnp.stack(period_keys))
+    logical["blocks"] = jax.tree.map(lambda lgx: ("layers",) + lgx,
+                                     box["logical"],
                                      is_leaf=lambda x: isinstance(x, tuple))
 
     if cfg.encoder is not None:
@@ -175,6 +181,22 @@ def init(key: jax.Array, cfg: ModelConfig):
                              * 0.01).astype(cfg.param_dtype)
         logical["enc_pos"] = ("seq", "embed_act")
     return params, logical
+
+
+@partial(jax.jit, static_argnums=1)
+def _init_params(key: jax.Array, cfg: ModelConfig):
+    return _init_tree(key, cfg)[0]
+
+
+def init(key: jax.Array, cfg: ModelConfig):
+    """Returns (params, logical). Blocks stacked across periods: leaf[P, ...].
+
+    One jitted program makes every leaf in ``cfg.param_dtype``: the device
+    holds the finished parameters plus the temporaries of the leaf being
+    made, never an fp32 copy of a bf16 model (llama3.2-3b fits one 16 GB
+    chip).
+    """
+    return _init_params(key, cfg), abstract_init(cfg)[1]
 
 
 # ---------------------------------------------------------------------------
@@ -442,8 +464,7 @@ def abstract_init(cfg: ModelConfig):
     box = {}
 
     def f(key):
-        params, logical = init(key, cfg)
-        box["logical"] = logical
+        params, box["logical"] = _init_tree(key, cfg)
         return params
 
     shapes = jax.eval_shape(f, jax.random.PRNGKey(0))

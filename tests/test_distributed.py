@@ -24,7 +24,7 @@ def run_with_devices(code: str, n: int = 8) -> dict:
 def test_pipeline_matches_sequential():
     r = run_with_devices(textwrap.dedent("""
         import json, jax, jax.numpy as jnp
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.distributed import pipeline as pp
         mesh = make_mesh((4,), ("pipe",))
         def layer(p, x):
@@ -55,7 +55,7 @@ def test_sharded_train_matches_single_device():
         params, logical = T.init(jax.random.PRNGKey(0), cfg)
         batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, cfg.vocab)}
         (l0, _), g0 = jax.value_and_grad(T.loss_fn, has_aux=True)(params, cfg, batch)
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((2, 4), ("data", "model"))
         with mesh, sharding_ctx(mesh):
             bs = jax.device_put(batch, NamedSharding(mesh, P("data")))
@@ -77,9 +77,9 @@ def test_gradient_compression_convergence():
         import json, jax, jax.numpy as jnp
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from repro import compat
         from repro.distributed import compression as C
-        mesh = compat.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         key = jax.random.PRNGKey(0)
         Wt = jax.random.normal(key, (16, 4))
         X = jax.random.normal(jax.random.PRNGKey(1), (64, 16))
@@ -93,7 +93,7 @@ def test_gradient_compression_convergence():
             err = C.init_error_state({"w": w})
 
             @jax.jit
-            @partial(compat.shard_map, mesh=mesh, in_specs=(P(), P("data"), P("data"), P()),
+            @partial(jax.shard_map, mesh=mesh, in_specs=(P(), P("data"), P("data"), P()),
                      out_specs=(P(), P()), check_vma=False)
             def step(w, x, y, e):
                 g = jax.grad(loss)(w, x, y)
@@ -120,7 +120,7 @@ def test_elastic_checkpoint_restore_across_meshes():
     code = textwrap.dedent("""
         import json, os, tempfile, jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.train.checkpoint import CheckpointManager
         d = tempfile.mkdtemp()
         mesh1 = make_mesh((4, 2), ("data", "model"))

@@ -221,9 +221,10 @@ def test_sharded_sweep_jaxpr_has_one_psum_per_scored_row():
     r = run_with_devices(textwrap.dedent("""
         import json, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro import compat, engine
+        from repro import engine
         from repro.core import factorizer as fz
         from repro.launch.mesh import make_host_mesh
+        from repro.launch.programs import primitive_names
 
         spec = engine.registry.build("lvrf_rows", jax.random.PRNGKey(0))
         cfg, cb = spec.cfg, spec.codebooks
@@ -241,25 +242,12 @@ def test_sharded_sweep_jaxpr_has_one_psum_per_scored_row():
         rs0 = fz.make_resonator(cb, cfg, None)
         st = rs0.init(qs, jax.random.split(jax.random.PRNGKey(0), 8))
         state_spec = type(st)(*([P("data")] * 5 + [P()]))
-        f = compat.shard_map(one_sweep, mesh=mesh,
-                             in_specs=(P(None, "model", None), P("data"),
-                                       state_spec),
-                             out_specs=state_spec, check_vma=False)
+        f = jax.shard_map(one_sweep, mesh=mesh,
+                          in_specs=(P(None, "model", None), P("data"),
+                                    state_spec),
+                          out_specs=state_spec, check_vma=False)
 
-        def prims(jaxpr, out):
-            for eqn in jaxpr.eqns:
-                out.append(eqn.primitive.name)
-                for v in eqn.params.values():
-                    for sub in jax.tree.leaves(
-                            v, is_leaf=lambda x: isinstance(
-                                x, (jax.core.Jaxpr, jax.core.ClosedJaxpr))):
-                        if isinstance(sub, jax.core.ClosedJaxpr):
-                            prims(sub.jaxpr, out)
-                        elif isinstance(sub, jax.core.Jaxpr):
-                            prims(sub, out)
-            return out
-
-        names = prims(jax.make_jaxpr(f)(cb, qs, st).jaxpr, [])
+        names = primitive_names(jax.make_jaxpr(f)(cb, qs, st))
         print(json.dumps({"psums": names.count("psum"), "F": int(F)}))
     """))
     assert r["psums"] == r["F"] + 1, r
@@ -337,9 +325,10 @@ def test_sharded_fused_sweep_jaxpr_has_one_psum_per_factor():
     r = run_with_devices(textwrap.dedent("""
         import json, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro import compat, engine
+        from repro import engine
         from repro.core import factorizer as fz
         from repro.launch.mesh import make_host_mesh
+        from repro.launch.programs import primitive_names
 
         spec = engine.registry.build("lvrf_rows", jax.random.PRNGKey(0),
                                      fused_step=True)
@@ -357,25 +346,12 @@ def test_sharded_fused_sweep_jaxpr_has_one_psum_per_factor():
         rs0 = fz.make_resonator(cb, cfg, None)
         st = rs0.init(qs, jax.random.split(jax.random.PRNGKey(0), 8))
         state_spec = type(st)(*([P("data")] * 5 + [P()]))
-        f = compat.shard_map(one_sweep, mesh=mesh,
-                             in_specs=(P(None, "model", None), P("data"),
-                                       state_spec),
-                             out_specs=state_spec, check_vma=False)
+        f = jax.shard_map(one_sweep, mesh=mesh,
+                          in_specs=(P(None, "model", None), P("data"),
+                                    state_spec),
+                          out_specs=state_spec, check_vma=False)
 
-        def prims(jaxpr, out):
-            for eqn in jaxpr.eqns:
-                out.append(eqn.primitive.name)
-                for v in eqn.params.values():
-                    for sub in jax.tree.leaves(
-                            v, is_leaf=lambda x: isinstance(
-                                x, (jax.core.Jaxpr, jax.core.ClosedJaxpr))):
-                        if isinstance(sub, jax.core.ClosedJaxpr):
-                            prims(sub.jaxpr, out)
-                        elif isinstance(sub, jax.core.Jaxpr):
-                            prims(sub, out)
-            return out
-
-        names = prims(jax.make_jaxpr(f)(cb, qs, st).jaxpr, [])
+        names = primitive_names(jax.make_jaxpr(f)(cb, qs, st))
         print(json.dumps({"psums": names.count("psum"), "F": int(F),
                           "pallas_calls": names.count("pallas_call")}))
     """))

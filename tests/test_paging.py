@@ -10,6 +10,7 @@ import pytest
 
 import repro.runtime as rt
 from repro.configs.registry import ARCHS
+from repro.launch.programs import primitive_names
 from repro.launch.serve import ServeEngine
 from repro.lm import model as lm_model
 from repro.lm.paging import BlockTablePool, PagedConfig
@@ -131,19 +132,6 @@ def test_one_pallas_call_per_decode_step(smoke):
     cfg, params = smoke
     eng = ServeEngine(cfg, params, 2, 32, paged=PagedConfig(block_size=8))
 
-    def prims(jaxpr, out):
-        for eqn in jaxpr.eqns:
-            out.append(eqn.primitive.name)
-            for v in eqn.params.values():
-                for sub in jax.tree.leaves(
-                        v, is_leaf=lambda x: isinstance(
-                            x, (jax.core.Jaxpr, jax.core.ClosedJaxpr))):
-                    if isinstance(sub, jax.core.ClosedJaxpr):
-                        prims(sub.jaxpr, out)
-                    elif isinstance(sub, jax.core.Jaxpr):
-                        prims(sub, out)
-        return out
-
     jaxpr = jax.make_jaxpr(
         lambda p, pool, table, lens, tok, act: lm_model.decode_step_paged(
             p, cfg, pool, table, lens, tok, act, use_flash=True,
@@ -151,7 +139,7 @@ def test_one_pallas_call_per_decode_step(smoke):
         params, eng.pool, jnp.asarray(eng.blocks.table()),
         jnp.zeros((2,), jnp.int32), jnp.zeros((2, 1), jnp.int32),
         jnp.ones((2,), bool))
-    names = prims(jaxpr.jaxpr, [])
+    names = primitive_names(jaxpr)
     assert names.count("pallas_call") == 1, names.count("pallas_call")
 
 

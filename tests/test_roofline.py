@@ -3,7 +3,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.compat import cost_analysis
 from repro.launch import roofline as R
 
 
@@ -21,8 +20,8 @@ def test_cost_analysis_counts_while_body_once():
     x = jnp.ones((64, 128))
     w = jnp.ones((128, 128))
     # n=1 unrolls (no while); compare two genuine loops instead
-    f4 = cost_analysis(jax.jit(make(4)).lower(x, w).compile())["flops"]
-    f16 = cost_analysis(jax.jit(make(16)).lower(x, w).compile())["flops"]
+    f4 = jax.jit(make(4)).lower(x, w).compile().cost_analysis()["flops"]
+    f16 = jax.jit(make(16)).lower(x, w).compile().cost_analysis()["flops"]
     assert f4 == f16  # if XLA ever fixes this, the analytic model can retire
 
 
