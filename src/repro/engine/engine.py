@@ -294,7 +294,6 @@ class Engine:
         self._next_id += 1
         for qi in range(k):
             self._queue.append((req, qi))
-        self.obs.count("submitted", 1, engine=self.obs_track)
         return req.id
 
     # -- serving loop ------------------------------------------------------
@@ -315,61 +314,77 @@ class Engine:
         return item
 
     def _fill(self) -> None:
-        fills = []
-        for slot in range(self.slots):
-            if self._owner[slot] is not None or not self._queue:
-                continue
-            req, qi = self._pop_next()
-            self._owner[slot] = (req, qi)
-            fills.append((slot, req.queries[qi], req.keys[qi]))
-        if not fills:
+        if not self._queue:
             return
-        # ONE fixed-shape jitted scatter for however many slots freed up:
-        # indices pad with `slots` (out of range -> dropped), so every fill
-        # count reuses the same compiled program.  The padded batch is
-        # assembled host-side — eager jnp.stack over a varying fill count
-        # would compile a fresh concatenate per distinct count.
-        idx = np.full(self.slots, self.slots, np.int32)
-        new_qs = np.zeros((self.slots, self.spec.dim), np.float32)
-        keys = np.zeros((self.slots,) + fills[0][2].shape,
-                        np.asarray(fills[0][2]).dtype)
-        for j, (slot, q, k) in enumerate(fills):
-            idx[j] = slot
-            new_qs[j] = np.asarray(q)
-            keys[j] = np.asarray(k)
-        with self.obs.span("fill", track=self.obs_track, cat="engine",
-                           args={"rows": len(fills)}):
+        with self.obs.span("fill", track=self.obs_track, cat="engine") as sp:
+            fills = []
+            for slot in range(self.slots):
+                if self._owner[slot] is not None or not self._queue:
+                    continue
+                req, qi = self._pop_next()
+                self._owner[slot] = (req, qi)
+                fills.append((slot, req.queries[qi], req.keys[qi]))
+            if sp is not None:
+                sp.args["rows"] = len(fills)
+            if not fills:
+                return
+            # ONE fixed-shape jitted scatter for however many slots freed
+            # up: indices pad with `slots` (out of range -> dropped), so
+            # every fill count reuses the same compiled program.  The padded
+            # batch is assembled host-side — eager jnp.stack over a varying
+            # fill count would compile a fresh concatenate per distinct
+            # count.
+            idx = np.full(self.slots, self.slots, np.int32)
+            new_qs = np.zeros((self.slots, self.spec.dim), np.float32)
+            keys = np.zeros((self.slots,) + fills[0][2].shape,
+                            np.asarray(fills[0][2]).dtype)
+            for j, (slot, q, k) in enumerate(fills):
+                idx[j] = slot
+                new_qs[j] = np.asarray(q)
+                keys[j] = np.asarray(k)
             self.qs, self.state = self._refill_many(
                 self.qs, self.state, jnp.asarray(idx), jnp.asarray(new_qs),
                 jnp.asarray(keys))
 
     def _retire(self) -> list:
-        done = np.asarray(self.state.done)
-        iters = np.asarray(self.state.iters)
-        max_it = self.spec.cfg.max_iters
+        """Retire ripe rows in three sibling spans: ``retire`` (the
+        ``done``/``iters`` pulls and the choice of ripe rows), ``decode``
+        (the batch decode, its pull and the per-row results) and one
+        ``postprocess`` per finished request (:meth:`_finalize`)."""
+        obs, track = self.obs, self.obs_track
+        with obs.span("retire", track=track, cat="engine"):
+            done = np.asarray(self.state.done)
+            iters = np.asarray(self.state.iters)
+            max_it = self.spec.cfg.max_iters
 
-        def budget(req):
-            # Per-request brownout trim: retire at the smaller cap.  The
-            # device sweep still checks cfg.max_iters, so a trimmed row is
-            # retired host-side at burst granularity (slight overshoot,
-            # same as LM max_new_tokens trimming at burst boundaries).
-            b = req.iter_budget
-            return max_it if b is None else min(max_it, b)
+            def budget(req):
+                # Per-request brownout trim: retire at the smaller cap.  The
+                # device sweep still checks cfg.max_iters, so a trimmed row
+                # is retired host-side at burst granularity (slight
+                # overshoot, same as LM max_new_tokens trimming at burst
+                # boundaries).
+                b = req.iter_budget
+                return max_it if b is None else min(max_it, b)
 
-        ripe = [s for s in range(self.slots)
-                if self._owner[s] is not None
-                and (done[s] or iters[s] >= budget(self._owner[s][0]))]
+            ripe = [s for s in range(self.slots)
+                    if self._owner[s] is not None
+                    and (done[s] or iters[s] >= budget(self._owner[s][0]))]
         if not ripe:
             return []
-        res = jax.device_get(self._decode(self.qs, self.state))
         finished = []
-        for s in ripe:
-            req, qi = self._owner[s]
-            self._owner[s] = None
-            req.rows[qi] = jax.tree.map(lambda a: a[s], res)
-            if all(r is not None for r in req.rows):
+        with obs.span("decode", track=track, cat="engine"):
+            res = jax.device_get(self._decode(self.qs, self.state))
+            for s in ripe:
+                req, qi = self._owner[s]
+                self._owner[s] = None
+                req.rows[qi] = jax.tree.map(lambda a: a[s], res)
+                if all(r is not None for r in req.rows):
+                    finished.append(req)
+        for req in finished:
+            with obs.span("postprocess", track=track, cat="engine",
+                          args={"queries": req.num_queries}
+                          if obs.enabled else None):
                 self._finalize(req)
-                finished.append(req)
         return finished
 
     def _finalize(self, req: Request) -> None:
@@ -387,28 +402,30 @@ class Engine:
 
     def step(self) -> list:
         """Fill free slots, run one adSCH-sized sweep burst, retire converged
-        rows.  Returns the requests completed by this step."""
+        rows.  Returns the requests completed by this step.
+
+        Traced, the ``step`` span holds the sibling phases ``fill``,
+        ``sweep-burst``, ``retire``, ``decode`` and ``postprocess``."""
         obs = self.obs
         with obs.span("step", track=self.obs_track, cat="engine") as sp:
             self._fill()
             if all(o is None for o in self._owner):
                 return []
+            burst_args = None
+            if obs.enabled:
+                burst_args = {"live": sum(o is not None for o in self._owner),
+                              "slots": self.slots}
             with obs.span("sweep-burst", track=self.obs_track,
-                          cat="engine") as bp:
+                          cat="engine", args=burst_args) as bp:
                 self.state, n = self._sweeps(self.qs, self.state,
                                              jnp.int32(self.sweeps_per_step))
                 n = int(n)  # host sync: the burst span covers device time
             self.sweeps_total += n
             self.steps_total += 1
-            with obs.span("retire", track=self.obs_track, cat="engine"):
-                finished = self._retire()
+            finished = self._retire()
         if obs.enabled:
             bp.args["sweeps"] = n
             sp.args.update(sweeps=n, retired=len(finished))
-            obs.count("steps", 1, engine=self.obs_track)
-            obs.count("sweeps", n, engine=self.obs_track)
-            if finished:
-                obs.count("completed", len(finished), engine=self.obs_track)
         return finished
 
     def drain(self, max_steps: int = 100_000) -> list:

@@ -30,18 +30,24 @@ class _SpanCtx:
     """Context manager over one stack-scoped span; yields the live Span so
     callers can attach args discovered mid-body (sweep counts, retirements).
     Reusable is NOT needed here — one per ``span()`` call on the enabled
-    path only."""
+    path only.  A ``TraceAnnotation`` entered and left with the span, on
+    the same thread, mirrors it into the profiler's trace as a host event,
+    so device ops in a profile sit under the engine phase that launched
+    them."""
 
-    __slots__ = ("_store", "_sid")
+    __slots__ = ("_store", "_sid", "_annotation")
 
-    def __init__(self, store, sid):
+    def __init__(self, store, sid, annotation):
         self._store = store
         self._sid = sid
+        self._annotation = annotation
 
     def __enter__(self):
+        self._annotation.__enter__()
         return self._store.get(self._sid)
 
     def __exit__(self, *exc):
+        self._annotation.__exit__(*exc)
         self._store.pop(self._sid)
         return False
 
@@ -68,6 +74,11 @@ class Recorder:
     enabled = True
 
     def __init__(self, *, clock=DEFAULT_CLOCK):
+        # imported here, not at module level: NullRecorder (the default)
+        # must not touch the profiler at all
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
         self.clock = clock
         self.t_epoch = clock()  # trace time zero (export offsets from here)
         self.spans = SpanStore(clock)
@@ -81,14 +92,19 @@ class Recorder:
     def span(self, name: str, *, track: str = "runtime",
              cat: str | None = None, args: dict | None = None):
         """Stack-scoped span: ``with rec.span("step", track=...) as sp:``.
-        Nested calls on the same track parent automatically."""
+        Nested calls on the same track parent automatically.  While the
+        span is open a ``jax.profiler.TraceAnnotation`` named
+        ``<track>/<name>`` is open too, so a profiler trace shows it as a
+        host event (a no-op when no profiler is running)."""
         return _SpanCtx(self.spans, self.spans.push(name, track=track,
-                                                    cat=cat, args=args))
+                                                    cat=cat, args=args),
+                        self._annotation(f"{track}/{name}"))
 
     def begin(self, name: str, *, track: str, parent: int | None = None,
               cat: str | None = None, args: dict | None = None) -> int:
         """Open a long-lived span (request lifecycle, fault cycle) whose
-        ``end`` happens on another code path; returns its id."""
+        ``end`` happens on another code path; returns its id.  Not mirrored
+        into the profiler trace: its end may come on another thread."""
         return self.spans.begin(name, track=track, parent=parent, cat=cat,
                                 args=args)
 

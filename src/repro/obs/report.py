@@ -8,10 +8,10 @@ the reader.  This module is that reader: it folds a finished
 - **per-request** decomposition: queue-wait (submit -> admit) vs service
   time, the service interval split across the engine phases that actually
   ran during it (``fill`` / ``sweep_burst`` / ``decode_burst`` /
-  ``retire`` / ``resize`` / ``replay``), supervision stalls
-  (``quarantine_backoff``, ``retune``), time the shared stepper spent
-  serving *other* engines (``cross_engine``), and an explicit ``other``
-  remainder for uninstrumented host work;
+  ``retire`` / ``decode`` / ``postprocess`` / ``resize`` / ``replay``),
+  supervision stalls (``quarantine_backoff``, ``retune``), time the shared
+  stepper spent serving *other* engines (``cross_engine``), and an
+  explicit ``other`` remainder for uninstrumented host work;
 - **per-engine** phase totals plus a span-derived modeled-vs-measured
   drift ratio: total burst seconds / total burst units against the
   planner's ``modeled_unit_s`` gauge — the same quantity as
@@ -45,11 +45,13 @@ from . import metrics as _metrics
 #: Bucket names in render order.  ``queue_wait`` is submit->admit; the rest
 #: decompose the service interval; ``other`` is the unattributed remainder.
 BUCKETS = ("queue_wait", "fill", "sweep_burst", "decode_burst", "retire",
-           "resize", "replay", "step_other", "retune", "quarantine_backoff",
-           "dispatch", "ingest", "cross_engine", "other")
+           "decode", "postprocess", "resize", "replay", "step_other",
+           "retune", "quarantine_backoff", "dispatch", "ingest",
+           "cross_engine", "other")
 
 _PHASE_NAMES = {"fill": "fill", "sweep-burst": "sweep_burst",
                 "decode-burst": "decode_burst", "retire": "retire",
+                "decode": "decode", "postprocess": "postprocess",
                 "resize": "resize", "recover": "replay"}
 
 (_PRIO_PHASE, _PRIO_STEP, _PRIO_SUPERVISION,

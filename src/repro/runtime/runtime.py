@@ -970,6 +970,11 @@ class Runtime:
         t.mark_tuned(rate)  # re-anchor either way; drift is vs the decision
 
     def _loop(self, gen: int) -> None:
+        # Traced, one runtime-track ``idle`` span covers each stretch of
+        # passes that find nothing to ingest or pick, from the first such
+        # pass to the next one that ingests or dispatches; it is entered
+        # and left on this thread, so it reaches the profiler's trace too.
+        idle = None
         try:
             while self._running and self._gen == gen:
                 lock = self._lock  # takeover swaps the attribute; pin per-pass
@@ -978,6 +983,7 @@ class Runtime:
                         return
                     now = self._clock()
                     if self._pending:
+                        idle = self._end_idle(idle)
                         # admission is real host work (engine submit() does
                         # device puts): span it so a burst's admission cost
                         # is attributable to the requests it delays.  The
@@ -989,6 +995,7 @@ class Runtime:
                     self._service_quarantine(now)
                     name = self._pick()
                     if name is not None:
+                        idle = self._end_idle(idle)
                         # dispatch span: covers the engine step PLUS the
                         # stepper's own host work around it (telemetry,
                         # gauges, future resolution) so the attribution
@@ -1006,6 +1013,10 @@ class Runtime:
                             # under the loop lock like every engine access
                             self.fleet.control(now=self._clock())
                 if name is None:
+                    if idle is None and self.obs.enabled:
+                        idle = self.obs.span("idle", track="runtime",
+                                             cat="runtime")
+                        idle.__enter__()
                     self._wake.wait(self._idle_sleep_s)
                     self._wake.clear()
         except _Takeover:  # stale generation: a replacement stepper owns
@@ -1025,6 +1036,15 @@ class Runtime:
                 fut = self._futures.get(gid)
                 if fut is not None and not fut.done():
                     fut.set_exception(e)
+        finally:
+            self._end_idle(idle)
+
+    @staticmethod
+    def _end_idle(idle):
+        """Close the stepper's open ``idle`` span, if any; returns None."""
+        if idle is not None:
+            idle.__exit__(None, None, None)
+        return None
 
     # -- watchdog thread ---------------------------------------------------
 

@@ -236,10 +236,11 @@ def _serve(eng, queries, keys):
     return eng.drain()
 
 
-def test_tracing_is_zero_overhead_bit_equal(lvrf_setup):
+def test_tracing_is_zero_overhead_bit_equal(lvrf_setup, monkeypatch):
     """The acceptance bar: with a live Recorder vs the NULL default, the
     same workload dispatches the same programs the same number of times and
-    every result is bit-equal — recording stays outside jit."""
+    every result is bit-equal — recording stays outside jit, and the NULL
+    path never calls the profiler."""
     spec, queries, keys = lvrf_setup
     rec = obs.Recorder()
     eng_on = engine.Engine(spec, slots=2, sweeps_per_step=2, obs=rec)
@@ -251,6 +252,11 @@ def test_tracing_is_zero_overhead_bit_equal(lvrf_setup):
     assert low[0] == low[1]
     c_on, c_off = _count_dispatches(eng_on), _count_dispatches(eng_off)
     done_on = _serve(eng_on, queries, keys)
+
+    def no_profiler(*a, **k):
+        raise AssertionError("the NULL path called the profiler")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", no_profiler)
     done_off = _serve(eng_off, queries, keys)
     assert c_on == c_off  # identical dispatch counts
     assert len(done_on) == len(done_off) == queries.shape[0]
@@ -259,11 +265,14 @@ def test_tracing_is_zero_overhead_bit_equal(lvrf_setup):
             np.asarray(x), np.asarray(y)), a.factorization, b.factorization)
     # and the traced run actually recorded the serving structure
     names = {s.name for s in rec.spans.snapshot()}
-    assert {"step", "sweep-burst", "retire", "fill"} <= names
-    snap = rec.metrics.snapshot()
-    assert snap["submitted"]["engine=lvrf_rows"] == queries.shape[0]
-    assert snap["sweeps"]["engine=lvrf_rows"] >= 1
-    assert obs.validate(rec.spans.snapshot()) == []
+    assert {"step", "fill", "sweep-burst", "retire", "decode",
+            "postprocess"} <= names
+    spans = rec.spans.snapshot()
+    assert eng_on.snapshot()["completed"] == queries.shape[0]
+    step_sweeps = sum(s.args["sweeps"] for s in spans if s.name == "step"
+                      and "sweeps" in s.args)
+    assert step_sweeps == eng_on.sweeps_total >= 1
+    assert obs.validate(spans) == []
 
 
 def test_engine_snapshot_nondestructive_stats_drains(lvrf_setup):
