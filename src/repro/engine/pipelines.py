@@ -37,7 +37,8 @@ def nvsa_abduction(key, *, cfg=None, params=None, batch: int = 8,
 
     Engine requests: the 8 context-panel queries of one task ([8, D]), with
     ``meta={"cand": [8, D]}`` candidate queries; the postprocess runs the
-    same beliefs -> abduce -> execute -> rank tail as :func:`nvsa.solve`.
+    same beliefs -> abduce -> execute -> rank tail as :func:`nvsa.solve`,
+    compiled once per spec, with one pull of its outputs a request.
     With ``params`` (a trained CNN) the ServeSpec also carries the runnable
     two-stage graph for stream serving.
 
@@ -58,16 +59,29 @@ def nvsa_abduction(key, *, cfg=None, params=None, batch: int = 8,
     graph = nvsa_mod.stage_graph(params, cbs, mask, cfg, batch=batch,
                                  expected_sweeps=expected_sweeps)
 
+    @jax.jit
+    def abduction_tail(queries, scores, cand, codebooks, valid_mask):
+        """The whole tail as ONE device program: run eagerly, each of its
+        ~600 primitives is a dispatch of its own.  Codebooks and mask are
+        arguments, not baked constants; ``cand=None`` traces the
+        beliefs-only twin."""
+        beliefs = nvsa_mod.beliefs_from_scores(queries, scores, valid_mask,
+                                               cfg)
+        if cand is None:
+            return beliefs, None, None
+        answer, sims = nvsa_mod.abduce_answers(beliefs[None], cand[None],
+                                               codebooks, cfg)
+        return beliefs, answer[0], sims[0]
+
     def postprocess(queries, res, meta):
-        beliefs = nvsa_mod.beliefs_from_scores(
-            jnp.asarray(queries), jnp.asarray(res.scores), mask, cfg)
+        cand = meta["cand"] if meta is not None and "cand" in meta else None
+        beliefs, answer, sims = jax.device_get(
+            abduction_tail(queries, res.scores, cand, cbs, mask))
         out = {"indices": res.indices, "iterations": res.iterations,
                "converged": res.converged, "beliefs": beliefs}
-        if meta is not None and "cand" in meta:
-            answer, sims = nvsa_mod.abduce_answers(
-                beliefs[None], jnp.asarray(meta["cand"])[None], cbs, cfg)
-            out["answer"] = int(answer[0])
-            out["sims"] = sims[0]
+        if cand is not None:
+            out["answer"] = int(answer)
+            out["sims"] = sims
         return out
 
     return ServeSpec("nvsa_abduction", cbs, cfg.factorizer, mask, graph,

@@ -1,5 +1,6 @@
 """Engine serving tests: StageGraph lowering, adSCH planning, continuous
 batching invariants, and parity with the in-process solve paths."""
+import logging
 import warnings
 
 import jax
@@ -157,6 +158,75 @@ def test_engine_request_answers_bit_equal_solve(nvsa_setup):
                                   np.asarray(want["fact_iters"][0]))
     np.testing.assert_allclose(np.asarray(req.result["sims"]),
                                np.asarray(want["sims"][0]), rtol=1e-5)
+
+
+def _nvsa_tail_inputs(seed, cfg, cbs):
+    """Seeded context queries, factorizer result and candidates of one task:
+    noisy bound atoms, so beliefs and answers are not degenerate."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    attrs = jnp.stack([jax.random.randint(k, (16,), 0, n) for k, n in
+                       zip(jax.random.split(ks[0], 3), nvsa.ATTR_SIZES)], -1)
+    qs = nvsa.target_query(cbs, attrs, cfg)
+    qs = qs + 0.3 * jax.random.normal(ks[1], qs.shape)
+    scores = jnp.einsum("fmd,nd->nfm", cbs, qs[:8])
+    scores = scores + 0.05 * jax.random.normal(ks[2], scores.shape)
+    res = fz.FactorizerResult(
+        np.asarray(attrs[:8]), np.full(8, 3, np.int32), np.ones(8, bool),
+        np.ones(8, np.float32), np.asarray(scores, np.float32))
+    return qs[:8], res, np.asarray(qs[8:])
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_nvsa_postprocess_matches_eager_tail(nvsa_setup, seed):
+    """The spec's compiled postprocess == the eager beliefs_from_scores ->
+    abduce_answers composition, with the result keys, types and shapes the
+    callers read; the no-``cand`` path returns the beliefs alone."""
+    cfg, _, _, _ = nvsa_setup
+    spec = engine.registry.build("nvsa_abduction", jax.random.PRNGKey(0),
+                                 cfg=cfg)
+    queries, res, cand = _nvsa_tail_inputs(seed, cfg, spec.codebooks)
+    beliefs = nvsa.beliefs_from_scores(queries, jnp.asarray(res.scores),
+                                       spec.valid_mask, cfg)
+    answer, sims = nvsa.abduce_answers(beliefs[None], jnp.asarray(cand)[None],
+                                       spec.codebooks, cfg)
+
+    out = spec.postprocess(queries, res, {"cand": cand})
+    assert set(out) == {"indices", "iterations", "converged", "beliefs",
+                        "answer", "sims"}
+    assert type(out["answer"]) is int and out["answer"] == int(answer[0])
+    assert np.asarray(out["sims"]).shape == (8,)
+    np.testing.assert_allclose(out["sims"], np.asarray(sims[0]), rtol=1e-5)
+    np.testing.assert_allclose(out["beliefs"], np.asarray(beliefs),
+                               rtol=1e-5)
+    np.testing.assert_array_equal(out["indices"], res.indices)
+
+    bare = spec.postprocess(queries, res, None)
+    assert set(bare) == {"indices", "iterations", "converged", "beliefs"}
+    assert np.asarray(bare["beliefs"]).shape == (8, len(nvsa.ATTR_SIZES),
+                                                 nvsa.MAX_M)
+    np.testing.assert_allclose(bare["beliefs"], np.asarray(beliefs),
+                               rtol=1e-5)
+
+
+def test_nvsa_postprocess_compiles_once(nvsa_setup, caplog):
+    """The tail is one program per spec: the first call compiles exactly
+    that program (eager primitives would compile many, or none once their
+    caches are warm), and later calls at the same shapes compile nothing."""
+    cfg, _, _, _ = nvsa_setup
+    spec = engine.registry.build("nvsa_abduction", jax.random.PRNGKey(0),
+                                 cfg=cfg)
+
+    def compiled(seed):
+        queries, res, cand = _nvsa_tail_inputs(seed, cfg, spec.codebooks)
+        caplog.clear()
+        with caplog.at_level(logging.WARNING), jax.log_compiles():
+            spec.postprocess(queries, res, {"cand": cand})
+        return [r.getMessage().split(" with ")[0] for r in caplog.records
+                if r.getMessage().startswith("Compiling ")]
+
+    assert compiled(3) == ["Compiling jit(abduction_tail)"]
+    assert compiled(4) == []
+    assert compiled(5) == []
 
 
 # ---------------------------------------------------------------------------
