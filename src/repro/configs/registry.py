@@ -1,11 +1,13 @@
 """Arch registry: --arch <id> resolution for launch/ and tests."""
-from repro.configs import (dbrx_132b, granite_moe_3b_a800m, jamba_1_5_large_398b,
-                           llama3_2_3b, minicpm_2b, qwen2_5_32b, qwen2_vl_72b,
-                           starcoder2_3b, whisper_small, xlstm_125m)
+from repro.configs import (dbrx_132b, deepseek_v2_lite, granite_moe_3b_a800m,
+                           jamba_1_5_large_398b, llama3_2_3b, minicpm_2b,
+                           qwen2_5_32b, qwen2_vl_72b, starcoder2_3b,
+                           whisper_small, xlstm_125m)
 
 ARCHS = {m.SPEC.arch_id: m.SPEC for m in (
     qwen2_vl_72b, granite_moe_3b_a800m, dbrx_132b, llama3_2_3b, minicpm_2b,
-    qwen2_5_32b, starcoder2_3b, xlstm_125m, whisper_small, jamba_1_5_large_398b)}
+    qwen2_5_32b, starcoder2_3b, xlstm_125m, whisper_small, jamba_1_5_large_398b,
+    deepseek_v2_lite)}
 
 
 def get(arch_id: str):

@@ -25,6 +25,12 @@ equal to the dense reference only within a small f32 tolerance (~1e-5
 relative; documented in DESIGN.md) — the serving-level contract (greedy
 token streams bit-equal across block sizes) is asserted in
 tests/test_paging.py on top of this.
+
+Latent (MLA) pools take the same grid and block table: with no separate
+value pool, one ``[bs, Dk]`` tile per grid step is the key (all ``Dk``
+lanes) and, through its first ``v_width`` lanes, the value, for every
+query head at once.  That call is named ``mla_decode`` so a
+profiler trace can find it.
 """
 from __future__ import annotations
 
@@ -38,12 +44,18 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG = -1e30
 
 
-def _body(table_ref, lens_ref, q_ref, k_ref, v_ref, *rest, block_size: int,
-          quantized: bool):
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
+def _body(table_ref, lens_ref, *rest, block_size: int, quantized: bool,
+          v_width: int | None, layered: bool):
+    latent = v_width is not None
+    if layered:  # the layer index only steers the index maps
+        rest = rest[1:]
+    q_ref, k_ref, *rest = rest
+    if latent:
         o_ref, m_ref, l_ref, acc_ref = rest
+    elif quantized:
+        v_ref, ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        v_ref, o_ref, m_ref, l_ref, acc_ref = rest
     b, i = pl.program_id(0), pl.program_id(1)
 
     @pl.when(i == 0)
@@ -57,81 +69,124 @@ def _body(table_ref, lens_ref, q_ref, k_ref, v_ref, *rest, block_size: int,
 
     @pl.when(start < valid_n)
     def _tile():
-        q = q_ref[0].astype(jnp.float32)       # [G, rep, dh]
-        k = k_ref[0].astype(jnp.float32)       # [bs, G, dh]
-        v = v_ref[0].astype(jnp.float32)
-        if quantized:
-            k = k * ks_ref[0]                  # [bs, G, 1] broadcast
-            v = v * vs_ref[0]
-        # scores: batch over G, contract dh -> [G, rep, bs]
-        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (1,))),
-                                preferred_element_type=jnp.float32)
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, 1, block_size), 2)
+        q = q_ref[0].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)
+        if latent:
+            # q [H, Dk]; the tile [bs, Dk] is K, its first lanes V
+            v = k[:, :v_width]
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            pv_dims = (((1,), (0,)), ((), ()))
+        else:
+            # q [G, rep, dh]; k, v tiles [bs, G, dh]
+            v = v_ref[0].astype(jnp.float32)
+            if quantized:
+                k = k * ks_ref[0]              # [bs, G, 1] broadcast
+                v = v * vs_ref[0]
+            # scores: batch over G, contract dh -> [G, rep, bs]
+            s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (1,))),
+                                    preferred_element_type=jnp.float32)
+            pv_dims = (((2,), (0,)), ((0,), (1,)))
+        pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, s.ndim - 1)
         s = jnp.where(pos < valid_n, s, _NEG)
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[..., None])
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=latent))
+        p = jnp.exp(s - (m_new if latent else m_new[..., None]))
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-        pv = jax.lax.dot_general(p, v, (((2,), (0,)), ((0,), (1,))),
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=latent)
+        pv = jax.lax.dot_general(p, v, pv_dims,
                                  preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr[..., None] + pv
+        acc_ref[...] = acc_ref[...] * (corr if latent else corr[..., None]) \
+            + pv
         m_ref[...] = m_new
 
     @pl.when(i == pl.num_programs(1) - 1)
     def _finish():
-        o_ref[...] = (acc_ref[...]
-                      / jnp.maximum(l_ref[...], 1e-30)[..., None])[None]
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[...] = (acc_ref[...] / (l if latent else l[..., None]))[None]
 
 
 def flash_decode(q, k_pool, v_pool, table, kv_lens, *, k_scale=None,
-                 v_scale=None, interpret: bool = False):
+                 v_scale=None, v_width: int | None = None, layer=None,
+                 interpret: bool = False):
     """Paged online-softmax decode attention (see ref.py for shapes).
 
-    Exactly one ``pallas_call`` per invocation — the jaxpr-checked serving
-    contract (tests/test_paging.py).
+    ``v_pool=None`` reads a latent pool ``[NBP, bs, Dk]`` with q ``[B, H,
+    Dk]``: the first ``v_width`` lanes of each key are its value, and the
+    result is ``[B, H, v_width]``.  With ``layer`` (a scalar) every pool
+    carries a leading layer axis and the kernel reads that layer's blocks
+    in place.  Exactly one ``pallas_call`` per invocation — the
+    jaxpr-checked serving contract (tests/test_paging.py).
     """
-    B, G, rep, dh = q.shape
+    latent = v_pool is None
+    layered = layer is not None
     W = table.shape[1]
-    bs = int(k_pool.shape[1])
+    bs = int(k_pool.shape[-2] if latent else k_pool.shape[-3])
     quantized = k_pool.dtype == jnp.int8
     if quantized and (k_scale is None or v_scale is None):
         raise ValueError("int8 KV pool requires k_scale/v_scale pools")
+    if latent and v_width is None:
+        raise ValueError("a latent pool (v_pool=None) needs v_width")
 
-    def _kv_index(b, i, tab, ln):
+    def _block(b, i, ln):
         # Clamp dead tiles (past the row's live window) to the LAST live
         # block: consecutive grid steps with an unchanged block index make
         # the pipeline skip the HBM->VMEM copy, so a row's KV traffic is
         # ceil(len/bs) block gathers — the structural win the cost model
         # prices — while @pl.when skips the compute.
         live = jnp.maximum((ln[b] + bs - 1) // bs, 1)
-        return (tab[b, jnp.minimum(i, live - 1)], 0, 0, 0)
+        return jnp.minimum(i, live - 1)
 
-    pool_spec = pl.BlockSpec((1, bs, G, dh), _kv_index)
-    in_specs = [
-        pl.BlockSpec((1, G, rep, dh), lambda b, i, tab, ln: (b, 0, 0, 0)),
-        pool_spec,
-        pool_spec,
-    ]
-    operands = [q.astype(jnp.float32), k_pool, v_pool]
-    if quantized:
-        scale_spec = pl.BlockSpec((1, bs, G, 1), _kv_index)
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scale, v_scale]
+    lead = (pl.Squeezed(),) if layered else ()
 
+    def _pool(*block):
+        """Block spec of a pool leaf: row b's i-th live block (of the
+        prefetched layer, when layered)."""
+        def index(b, i, tab, ln, *lay):
+            at = (lay[0][0],) if layered else ()
+            return (*at, tab[b, _block(b, i, ln)], *(0,) * len(block))
+        return pl.BlockSpec(lead + (1,) + block, index)
+
+    if latent:
+        B, H, Dk = q.shape
+        pool_spec = _pool(bs, Dk)
+        row = lambda b, i, *_: (b, 0, 0)  # noqa: E731
+        in_specs = [pl.BlockSpec((1, H, Dk), row), pool_spec]
+        operands = [q.astype(jnp.float32), k_pool]
+        out_block, out_shape = (1, H, v_width), (B, H, v_width)
+        stats = (H, 1)
+        name = "mla_decode"
+    else:
+        B, G, rep, dh = q.shape
+        pool_spec = _pool(bs, G, dh)
+        row = lambda b, i, *_: (b, 0, 0, 0)  # noqa: E731
+        in_specs = [pl.BlockSpec((1, G, rep, dh), row), pool_spec, pool_spec]
+        operands = [q.astype(jnp.float32), k_pool, v_pool]
+        if quantized:
+            scale_spec = _pool(bs, G, 1)
+            in_specs += [scale_spec, scale_spec]
+            operands += [k_scale, v_scale]
+        out_block, out_shape = (1, G, rep, dh), (B, G, rep, dh)
+        stats = (G, rep)
+        name = None
+
+    prefetch = [table, kv_lens]
+    if layered:
+        prefetch.append(jnp.asarray(layer, jnp.int32).reshape(1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(prefetch),
         grid=(B, W),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, G, rep, dh),
-                               lambda b, i, tab, ln: (b, 0, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((G, rep), jnp.float32),
-                        pltpu.VMEM((G, rep), jnp.float32),
-                        pltpu.VMEM((G, rep, dh), jnp.float32)],
+        out_specs=pl.BlockSpec(out_block, row),
+        scratch_shapes=[pltpu.VMEM(stats, jnp.float32),
+                        pltpu.VMEM(stats, jnp.float32),
+                        pltpu.VMEM(out_shape[1:], jnp.float32)],
     )
     return pl.pallas_call(
-        partial(_body, block_size=bs, quantized=quantized),
+        partial(_body, block_size=bs, quantized=quantized,
+                v_width=v_width if latent else None, layered=layered),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, G, rep, dh), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
         interpret=interpret,
-    )(table, kv_lens, *operands)
+        name=name,
+    )(*prefetch, *operands)

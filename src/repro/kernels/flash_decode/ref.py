@@ -21,6 +21,11 @@ Shared layout contract (ref + kernel):
   * k_scale/v_scale: [NBP, bs, G, 1] f32 when the pool is int8.
 
 Returns [B, G, rep, dh] f32 (un-projected per-head context).
+
+A latent (MLA) pool has no value pool (``v_pool=None``): k_pool is
+[NBP, bs, Dk], q is [B, H, Dk] (every head reads the same entries), the
+value of a position is the first ``v_width`` lanes of its key, and the
+result is [B, H, v_width].
 """
 from __future__ import annotations
 
@@ -29,11 +34,19 @@ import jax.numpy as jnp
 
 
 def flash_decode_ref(q, k_pool, v_pool, table, kv_lens,
-                     k_scale=None, v_scale=None):
-    B, G, rep, dh = q.shape
+                     k_scale=None, v_scale=None, v_width=None):
     W = table.shape[1]
+    k = k_pool[table].astype(jnp.float32)  # [B, W, bs, G, dh] | [B, W, bs, Dk]
+    if v_pool is None:
+        B, bs = q.shape[0], k_pool.shape[1]
+        k = k.reshape(B, W * bs, k.shape[-1])
+        s = jnp.einsum("bhd,bkd->bhk", q.astype(jnp.float32), k)
+        pos = jnp.arange(W * bs)
+        s = jnp.where(pos[None, None, :] < kv_lens[:, None, None], s, -1e30)
+        w = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("bhk,bkd->bhd", w, k[..., :v_width])
+    B, G, rep, dh = q.shape
     bs = k_pool.shape[1]
-    k = k_pool[table].astype(jnp.float32)  # [B, W, bs, G, dh]
     v = v_pool[table].astype(jnp.float32)
     if k_scale is not None:
         k = k * k_scale[table]

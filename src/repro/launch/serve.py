@@ -15,6 +15,7 @@ Two device layouts behind one API:
     per ``prefill_chunk`` tokens instead of one per token), flash-decode
     attention (:mod:`repro.kernels.flash_decode`), capacity limited by the
     pool instead of ``max_len``, and ``resize()`` as a block-table edit.
+    Latent-attention (MLA) stacks serve here only.
 
     PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-3b --smoke
 """
@@ -39,6 +40,12 @@ from repro.nn import transformer as T
 log = logging.getLogger(__name__)
 
 
+def _greedy(logits):
+    """(argmax token [B] int32, its logit [B] f32) of the last position."""
+    last = logits[:, -1]
+    return jnp.argmax(last, axis=-1).astype(jnp.int32), jnp.max(last, axis=-1)
+
+
 class ServeEngine:
     """Static-batch continuous batching over a shared KV cache."""
 
@@ -61,6 +68,9 @@ class ServeEngine:
         self.obs_track = obs_track
         self.active = np.zeros(batch_slots, bool)
         self.generated: list = [[] for _ in range(batch_slots)]
+        # f32 logit of each generated token (generated[s][1:]), pulled in
+        # the same transfer as the token ids
+        self.generated_logits: list = [[] for _ in range(batch_slots)]
         # Host mirror of each slot's KV length + capacity parking flags: a
         # decode step writes KV at position len, so a slot out of KV room
         # must NOT step again.  step() parks such slots (active=False,
@@ -75,6 +85,13 @@ class ServeEngine:
         self.prefill_dispatches = 0
         self.decode_dispatches = 0
         self.kv_bytes_touched = 0
+        # token-expert picks of live decode rows on the experts held here
+        # (expert-share MoE stacks; pulled with each step's tokens), and
+        # the (layer, held expert) pairs they fall on
+        self.held_picks_total = 0
+        moe = cfg.moe if cfg.moe is not None and cfg.moe.held else None
+        self.held_experts = 0 if moe is None else moe.held * cfg.n_periods \
+            * sum(k == "attn_moe" for k in cfg.block_pattern)
         # [slots, 1, vocab] fp32 logits of the latest decode step (None
         # before the first): what layouts and kernels are compared on
         self.last_logits = None
@@ -88,12 +105,16 @@ class ServeEngine:
             # The pool is donated through every dispatch (it is THE mutable
             # serving state); closures carry no batch dim, so resize() is
             # pure host-side re-slotting + an automatic shape recompile.
-            self._decode_paged = jax.jit(
-                lambda p, pool, table, lens, tok, act:
-                lm_model.decode_step_paged(
+            # the greedy pick and its logit ride in the decode program, so
+            # one pull per step brings tokens, logits and counters back
+            def decode(p, pool, table, lens, tok, act):
+                logits, pool, held = lm_model.decode_step_paged(
                     p, cfg, pool, table, lens, tok, act,
-                    use_flash=paged.use_flash, interpret=paged.interpret),
-                donate_argnums=(1,))
+                    use_flash=paged.use_flash, interpret=paged.interpret)
+                return (logits, pool, *_greedy(logits),
+                        jnp.sum(jnp.where(act, held, 0)))
+
+            self._decode_paged = jax.jit(decode, donate_argnums=(1,))
             self._prefill_paged = jax.jit(
                 lambda p, pool, row_table, len0, tok, count:
                 lm_model.prefill_chunk_paged(p, cfg, pool, row_table, len0,
@@ -115,7 +136,12 @@ class ServeEngine:
                 c, new)
             return logits, merged
 
-        self._decode = jax.jit(decode_masked)
+        # the greedy pick and its logit ride in the program, as on the pool
+        def decode_greedy(p, c, tok, act):
+            logits, merged = decode_masked(p, c, tok, act)
+            return (logits, merged, *_greedy(logits))
+
+        self._decode = jax.jit(decode_greedy)
         # Prefill one token into ONE slot: decode the whole (static-shape)
         # batch but write back only the target slot's row.
         self._prefill = jax.jit(lambda p, c, tok, slot: decode_masked(
@@ -143,14 +169,16 @@ class ServeEngine:
             return self.max_len
         return self.blocks.slot_capacity
 
-    def can_admit(self, tokens: int) -> bool:
+    def can_admit(self, tokens: int, reserved: int = 0) -> bool:
         """Whether a fresh ``tokens``-token prompt can be admitted NOW
-        (paged: enough free blocks; contiguous: fits the row)."""
+        (paged: enough free blocks beyond ``reserved`` of them;
+        contiguous: fits the row)."""
         if tokens > self.slot_capacity:
             return False
         if self.paged is None:
             return True
-        return self.blocks.free_blocks >= cdiv(tokens, self.paged.block_size)
+        return self.blocks.free_blocks - reserved >= \
+            cdiv(tokens, self.paged.block_size)
 
     def _kv_step_bytes(self) -> int:
         """Modeled KV bytes one decode dispatch reads (all attn layers)."""
@@ -160,8 +188,10 @@ class ServeEngine:
             cfg.d_model // cfg.n_heads
         int8 = cfg.kv_cache_dtype == "int8"
         per_tok = 2 * G * dh * (1 if int8 else 2) + (2 * G * 4 if int8 else 0)
+        if cfg.mla is not None:  # one bf16 latent entry serves every head
+            per_tok = 2 * cfg.mla.latent_dim
         n_attn = sum(k.startswith("attn") for k in cfg.block_pattern) \
-            * cfg.n_periods
+            * cfg.n_periods + cfg.first_dense
         if self.paged is None:
             window = self.slots * self.max_len  # dense read of the full cache
         elif self.paged.use_flash:
@@ -178,6 +208,7 @@ class ServeEngine:
         """Stop serving a slot and (paged) return its blocks to the pool."""
         self.active[slot] = False
         self.sampling[slot] = None
+        self.generated_logits[slot] = []
         if self.paged is not None:
             self.blocks.release(slot)
 
@@ -232,9 +263,9 @@ class ServeEngine:
                                                    "tokens": count}):
                     lg, self.pool = self._prefill_paged(
                         self.params, self.pool, row_table, jnp.int32(c0),
-                        jnp.asarray(padded)[None], jnp.int32(count))
+                        jnp.asarray(padded[None]), jnp.int32(count))
                 self.prefill_dispatches += 1
-                logits = lg[:, count - 1]
+                logits = lg
         else:
             with self.obs.span("prefill", track=self.obs_track, cat="lm",
                                args={"slot": slot, "tokens": n - 1}):
@@ -251,6 +282,7 @@ class ServeEngine:
                            engine=self.obs_track)
         self.active[slot] = True
         self.generated[slot] = [int(prompt[-1])]
+        self.generated_logits[slot] = []
         self.lens[slot] = n - 1
         self.overflowed[slot] = False
         self.sampling[slot] = sampling
@@ -303,13 +335,14 @@ class ServeEngine:
             self.generated[s][-1] if self.generated[s] else 0
             for s in range(self.slots)], dtype=jnp.int32)[:, None]
         if self.paged is not None:
-            logits, self.pool = self._decode_paged(
+            logits, self.pool, top, top_logit, held = self._decode_paged(
                 self.params, self.pool, jnp.asarray(self.blocks.table()),
                 jnp.asarray(self.lens, jnp.int32), last,
                 jnp.asarray(self.active))
         else:
-            logits, self.cache = self._decode(self.params, self.cache, last,
-                                              jnp.asarray(self.active))
+            logits, self.cache, top, top_logit = self._decode(
+                self.params, self.cache, last, jnp.asarray(self.active))
+            held = 0
         self.last_logits = logits
         self.decode_dispatches += 1
         kv_bytes = self._kv_step_bytes()
@@ -319,18 +352,26 @@ class ServeEngine:
             self.obs.count("kv_bytes_touched", kv_bytes,
                            engine=self.obs_track)
         self.lens[self.active] += 1
-        if sampler == "greedy":
-            nxt = np.array(jnp.argmax(logits[:, -1], axis=-1))
-        else:
+        nxt, nxt_logit, held = jax.device_get((top, top_logit, held))
+        nxt, nxt_logit = np.array(nxt), np.array(nxt_logit)
+        self.held_picks_total += int(held)
+        sampled = sampler != "greedy"
+        if sampled:
             nxt = np.array(jax.random.categorical(
                 key, logits[:, -1] / temperature))
         for s in range(self.slots):
-            if not self.active[s]:
-                continue
-            if self.sampling[s] is not None:
+            if self.active[s] and self.sampling[s] is not None:
                 nxt[s] = lm_sampling.sample_token(
                     logits[s, -1], self.sampling[s], int(self.lens[s]))
+                sampled = True
+        if sampled:  # the chosen tokens' logits, in one gather and pull
+            nxt_logit = np.array(jnp.take_along_axis(
+                logits[:, -1], jnp.asarray(nxt, jnp.int32)[:, None], -1)[:, 0])
+        for s in range(self.slots):
+            if not self.active[s]:
+                continue
             self.generated[s].append(int(nxt[s]))
+            self.generated_logits[s].append(float(nxt_logit[s]))
         return jnp.asarray(nxt)
 
     # -- warm handoff ------------------------------------------------------
@@ -361,6 +402,8 @@ class ServeEngine:
             + [False] * (slots - len(carry)), bool)
         self.generated = [self.generated[c] for c in carry] + \
             [[] for _ in range(slots - len(carry))]
+        self.generated_logits = [self.generated_logits[c] for c in carry] \
+            + [[] for _ in range(slots - len(carry))]
         self.sampling = [self.sampling[c] for c in carry] + \
             [None] * (slots - len(carry))
         self.slots = slots

@@ -1,4 +1,5 @@
-"""Core transformer building blocks: norms, RoPE/M-RoPE, GQA attention, MLPs.
+"""Core transformer building blocks: norms, RoPE/M-RoPE/YaRN, GQA and
+latent (MLA) attention, MLPs.
 
 Pure-JAX pytree modules.  Every `init_*` returns `(params, logical)` where
 `logical` mirrors the params tree with logical-axis tuples for sharding
@@ -12,10 +13,12 @@ Pure-JAX pytree modules.  Every `init_*` returns `(params, logical)` where
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.nn.common import shard
 
@@ -159,18 +162,22 @@ def _qkv(p, x, cfg: AttnConfig, positions):
     return q, k, v
 
 
-def flash_attention(q, k, v, *, causal: bool, block: int, q_offset=0) -> jax.Array:
+def flash_attention(q, k, v, *, causal: bool, block: int, q_offset=0,
+                    scale: float | None = None) -> jax.Array:
     """Blockwise-softmax attention: lax.scan over KV blocks, O(S*block) memory.
 
-    q: [B, Sq, H, dh]; k, v: [B, Sk, G, dh] with H = G * rep (GQA).  KV heads
-    are repeated up to H *inside* the kernel so every intermediate carries a
-    plain heads axis — the layout that shards cleanly over `model` (grouped
-    [.., G, rep, ..] layouts make GSPMD fall back to replication).
+    q, k: [B, S, H|G, dh]; v: [B, Sk, G, dv] with H = G * rep (GQA); ``dv``
+    may differ from ``dh`` (MLA) and ``scale`` defaults to ``dh ** -0.5``.
+    KV heads are repeated up to H *inside* the kernel so every intermediate
+    carries a plain heads axis — the layout that shards cleanly over
+    `model` (grouped [.., G, rep, ..] layouts make GSPMD fall back to
+    replication).
     """
     B, Sq, H, dh = q.shape
     Sk, G = k.shape[1], k.shape[2]
+    dv = v.shape[-1]
     rep = H // G
-    scale = dh ** -0.5
+    scale = dh ** -0.5 if scale is None else scale
     qf = (q.astype(jnp.float32) * scale)
     if rep > 1:
         k = jnp.repeat(k, rep, axis=2)  # [B, Sk, H, dh]
@@ -181,7 +188,7 @@ def flash_attention(q, k, v, *, causal: bool, block: int, q_offset=0) -> jax.Arr
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     nb = k.shape[1] // block
     kb = jnp.moveaxis(k.reshape(B, nb, block, H, dh), 1, 0)  # [nb, B, blk, H, dh]
-    vb = jnp.moveaxis(v.reshape(B, nb, block, H, dh), 1, 0)
+    vb = jnp.moveaxis(v.reshape(B, nb, block, H, dv), 1, 0)
     q_pos = q_offset + jnp.arange(Sq)
 
     def body(carry, inp):
@@ -204,14 +211,14 @@ def flash_attention(q, k, v, *, causal: bool, block: int, q_offset=0) -> jax.Arr
 
     m0 = jnp.full((B, Sq, H), -1e30, jnp.float32)
     l0 = jnp.zeros((B, Sq, H), jnp.float32)
-    a0 = jnp.zeros((B, Sq, H, dh), jnp.float32)
+    a0 = jnp.zeros((B, Sq, H, dv), jnp.float32)
     # checkpoint the block body: without it the scan saves the [.., block]
     # probability tensor for EVERY block for the backward pass (O(S^2) memory,
     # defeating the point of the streaming formulation).
     (m, l, acc, _), _ = jax.lax.scan(jax.checkpoint(body),
                                      (m0, l0, a0, jnp.int32(0)), (kb, vb))
     out = acc / jnp.maximum(l[..., None], 1e-30)
-    return out.reshape(B, Sq, H, dh).astype(q.dtype)
+    return out.reshape(B, Sq, H, dv).astype(q.dtype)
 
 
 def attention(p, x, cfg: AttnConfig, positions=None) -> jax.Array:
@@ -305,7 +312,19 @@ def init_kv_pool(num_blocks: int, block_size: int, cfg: AttnConfig,
     return pool
 
 
-def _pool_write(pool: dict, phys, off, k_new, v_new):
+def _at(leaf, layer, *idx):
+    """``leaf.at[...]`` of one layer's block entries: pool leaves carry a
+    leading layer axis when ``layer`` is given (the whole stack's pool,
+    updated in place), none when it is ``None``."""
+    return leaf.at[idx if layer is None else (layer,) + idx]
+
+
+def _blocks(leaf, layer, idx):
+    """The blocks ``idx`` of one layer's pool leaf (see :func:`_at`)."""
+    return leaf[idx] if layer is None else leaf[layer, idx]
+
+
+def _pool_write(pool: dict, phys, off, k_new, v_new, layer=None):
     """Scatter one token per row into the pool at (phys[r], off[r]).
     k_new/v_new: [R, G, dh] (one token per row, any leading row count)."""
     quantized = pool["k"].dtype == jnp.int8
@@ -313,23 +332,23 @@ def _pool_write(pool: dict, phys, off, k_new, v_new):
     if quantized:
         kq, ks = _quant_kv(k_new)
         vq, vs = _quant_kv(v_new)
-        for name, val in (("k", kq), ("v", vq), ("k_scale", ks),
-                          ("v_scale", vs)):
-            new_pool[name] = pool[name].at[phys, off].set(
-                val.astype(pool[name].dtype))
+        vals = (("k", kq), ("v", vq), ("k_scale", ks), ("v_scale", vs))
     else:
-        for name, val in (("k", k_new), ("v", v_new)):
-            new_pool[name] = pool[name].at[phys, off].set(
-                val.astype(pool[name].dtype))
+        vals = (("k", k_new), ("v", v_new))
+    for name, val in vals:
+        new_pool[name] = _at(pool[name], layer, phys, off).set(
+            val.astype(pool[name].dtype))
     return new_pool
 
 
 def attention_decode_paged(p, x, pool: dict, cfg: AttnConfig, table, kv_lens,
                            active, *, use_flash: bool = True,
-                           interpret: bool | None = None) -> tuple:
+                           interpret: bool | None = None,
+                           layer=None) -> tuple:
     """Single-token decode against a paged KV pool.
 
-    x: [B, 1, d]; pool: {'k','v': [NBP, bs, G, dh]} (+ scales when int8);
+    x: [B, 1, d]; pool: {'k','v': [NBP, bs, G, dh]} (+ scales when int8),
+    or with a leading layer axis read and written at ``layer``;
     table: [B, W] int32 block table; kv_lens: [B] int32 pre-write lengths;
     active: [B] bool — inactive rows write their KV to the trash block (and
     their output is garbage the caller ignores).  Returns (out, new_pool).
@@ -338,25 +357,25 @@ def attention_decode_paged(p, x, pool: dict, cfg: AttnConfig, table, kv_lens,
 
     B = x.shape[0]
     q, k_new, v_new = _qkv(p, x, cfg, kv_lens[:, None])
-    bs = pool["k"].shape[1]
-    trash = pool["k"].shape[0] - 1
+    _, bs, G, _ = pool["k"].shape[-4:]
+    trash = pool["k"].shape[-4] - 1
     W = table.shape[1]
     rows = jnp.arange(B)
     blk = jnp.minimum(kv_lens // bs, W - 1)
     phys = jnp.where(active, table[rows, blk], trash)
     off = kv_lens % bs
-    new_pool = _pool_write(pool, phys, off, k_new[:, 0], v_new[:, 0])
-    G = pool["k"].shape[2]
+    new_pool = _pool_write(pool, phys, off, k_new[:, 0], v_new[:, 0], layer)
     rep = cfg.n_heads // G
     qf = (q.astype(jnp.float32) * cfg.dh ** -0.5).reshape(B, G, rep, cfg.dh)
     out = _fd.flash_decode(qf, new_pool, table, kv_lens + 1,
-                           use_flash=use_flash, interpret=interpret)
+                           use_flash=use_flash, interpret=interpret,
+                           layer=layer)
     out = out.reshape(B, 1, cfg.n_heads * cfg.dh).astype(x.dtype)
     return shard(dense(p["o"], out), "batch", None, "embed_act"), new_pool
 
 
 def attention_prefill_paged(p, x, pool: dict, cfg: AttnConfig, row_table,
-                            len0, count) -> tuple:
+                            len0, count, layer=None) -> tuple:
     """Chunked prefill for ONE slot against the paged pool.
 
     x: [1, C, d] — a static-width chunk whose first ``count`` tokens are
@@ -369,17 +388,17 @@ def attention_prefill_paged(p, x, pool: dict, cfg: AttnConfig, row_table,
     C = x.shape[1]
     idx = len0 + jnp.arange(C)                       # absolute positions [C]
     q, k_new, v_new = _qkv(p, x, cfg, idx[None])
-    bs = pool["k"].shape[1]
-    trash = pool["k"].shape[0] - 1
+    bs = pool["k"].shape[-3]
+    trash = pool["k"].shape[-4] - 1
     W = row_table.shape[0]
     within = jnp.arange(C) < count
     phys = jnp.where(within, row_table[jnp.minimum(idx // bs, W - 1)], trash)
-    new_pool = _pool_write(pool, phys, idx % bs, k_new[0], v_new[0])
-    k = new_pool["k"][row_table].astype(jnp.float32)  # [W, bs, G, dh]
-    v = new_pool["v"][row_table].astype(jnp.float32)
-    if "k_scale" in new_pool:
-        k = k * new_pool["k_scale"][row_table]
-        v = v * new_pool["v_scale"][row_table]
+    new_pool = _pool_write(pool, phys, idx % bs, k_new[0], v_new[0], layer)
+    k = _blocks(new_pool["k"], layer, row_table).astype(jnp.float32)
+    v = _blocks(new_pool["v"], layer, row_table).astype(jnp.float32)
+    if "k_scale" in new_pool:  # [W, bs, G, dh]
+        k = k * _blocks(new_pool["k_scale"], layer, row_table)
+        v = v * _blocks(new_pool["v_scale"], layer, row_table)
     G, dh = k.shape[2], k.shape[3]
     k = k.reshape(W * bs, G, dh)
     v = v.reshape(W * bs, G, dh)
@@ -392,6 +411,295 @@ def attention_prefill_paged(p, x, pool: dict, cfg: AttnConfig, row_table,
     out = jnp.einsum("bcgrk,kgd->bcgrd", w, v)
     out = out.reshape(1, C, cfg.n_heads * cfg.dh).astype(x.dtype)
     return shard(dense(p["o"], out), "batch", "seq", "embed_act"), new_pool
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (MLA, DeepSeek-V2 arXiv:2405.04434 §2.1)
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Latent attention without a query low-rank (DeepSeek-V2-Lite's form).
+
+    Keys and values come from one per-token latent: ``c = RMSNorm(x W_DKV)``
+    (``kv_lora_rank`` wide) and a decoupled, roped key ``k_R = RoPE(x W_KR)``
+    (``qk_rope_head_dim`` wide) shared by every head.  The paged cache holds
+    ``[c, k_R]``, one ``latent_dim``-wide entry per token and layer.  RoPE is
+    YaRN-scaled when ``rope_factor > 1`` (DeepSeek-V2's YaRN rotary
+    embedding: frequencies blended between interpolation and extrapolation
+    over the correction range of ``beta_fast`` / ``beta_slow`` rotations at
+    ``rope_original_max`` positions, softmax scale times ``mscale(factor,
+    mscale_all_dim)**2``).
+    """
+
+    d_model: int
+    n_heads: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float = 1e4
+    rope_factor: float = 1.0
+    rope_original_max: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+    eps: float = 1e-6
+
+    @property
+    def q_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """Width of one cached token: the latent plus the roped key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def pool_width(self) -> int:
+        """Lanes of one pool entry: ``latent_dim`` padded with zeros to a
+        multiple of 128, the TPU's lane tile."""
+        return -(-self.latent_dim // 128) * 128
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_correction_dim(rotations: float, dim: int, base: float,
+                         max_pos: int) -> float:
+    return (dim * math.log(max_pos / (rotations * 2 * math.pi))) / \
+        (2 * math.log(base))
+
+
+def mla_inv_freq(cfg: MLAConfig) -> np.ndarray:
+    """[qk_rope_head_dim / 2] f32 inverse frequencies of the roped lanes.
+
+    Lanes below the correction range keep the base frequency
+    (extrapolation), lanes above it are divided by ``rope_factor``
+    (interpolation), with a linear ramp between.
+    """
+    dim, base = cfg.qk_rope_head_dim, cfg.rope_theta
+    extra = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if cfg.rope_factor <= 1:
+        return extra.astype(np.float32)
+    lo = max(math.floor(_yarn_correction_dim(
+        cfg.beta_fast, dim, base, cfg.rope_original_max)), 0)
+    hi = min(math.ceil(_yarn_correction_dim(
+        cfg.beta_slow, dim, base, cfg.rope_original_max)), dim - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = np.clip((np.arange(dim // 2) - lo) / (hi - lo), 0.0, 1.0)
+    keep = 1.0 - ramp  # 1: extrapolate, 0: interpolate
+    return (extra / cfg.rope_factor * (1 - keep) + extra * keep).astype(
+        np.float32)
+
+
+def mla_softmax_scale(cfg: MLAConfig) -> float:
+    m = yarn_mscale(cfg.rope_factor, cfg.mscale_all_dim) \
+        if cfg.mscale_all_dim else 1.0
+    return cfg.q_head_dim ** -0.5 * m * m
+
+
+def apply_rope_interleaved(x: jax.Array, positions: jax.Array,
+                           inv_freq) -> jax.Array:
+    """HF ``modeling_deepseek``'s rotation: the lanes of ``x [..., S, H, d]``
+    are de-interleaved (even lanes, then odd) and rotated as halves.  The
+    YaRN cos/sin scale mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim) is 1 for DeepSeek-V2 and is left out."""
+    ang = positions[..., None].astype(jnp.float32) * inv_freq  # [B, S, d/2]
+    cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.astype(x.dtype)
+
+
+def init_mla(key, cfg: MLAConfig):
+    H, r = cfg.n_heads, cfg.kv_lora_rank
+    ks = jax.random.split(key, 5)
+    p, lg = {}, {}
+    p["q"], lg["q"] = _dense_init(ks[0], cfg.d_model, H * cfg.q_head_dim,
+                                  ("embed", "heads"))
+    p["kv_a"], lg["kv_a"] = _dense_init(ks[1], cfg.d_model, cfg.latent_dim,
+                                        ("embed", None))
+    p["kv_ln"], lg["kv_ln"] = init_rmsnorm(r)
+    lg["kv_ln"] = {"scale": (None,)}
+    # kv_b of the published checkpoint, split by what it makes: W_UK gives
+    # the per-head non-roped key, W_UV the per-head value
+    p["uk"], lg["uk"] = _dense_init(ks[2], r, H * cfg.qk_nope_head_dim,
+                                    (None, "heads"))
+    p["uv"], lg["uv"] = _dense_init(ks[3], r, H * cfg.v_head_dim,
+                                    (None, "heads"))
+    p["o"], lg["o"] = _dense_init(ks[4], H * cfg.v_head_dim, cfg.d_model,
+                                  ("heads", "embed"))
+    return p, lg
+
+
+def mla_query(p, x, cfg: MLAConfig, positions):
+    """(q_nope [B, S, H, nope], roped q_pe [B, S, H, rope])."""
+    B, S, _ = x.shape
+    q = dense(p["q"], x).reshape(B, S, cfg.n_heads, cfg.q_head_dim)
+    q_nope, q_pe = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
+    return q_nope, apply_rope_interleaved(q_pe, positions, mla_inv_freq(cfg))
+
+
+def mla_latent(p, x, cfg: MLAConfig, positions):
+    """The cached entry of each token: ``[RMSNorm(c), RoPE(k_R)]``,
+    [B, S, latent_dim] in ``x``'s dtype."""
+    kv = dense(p["kv_a"], x)
+    c, k_pe = jnp.split(kv, [cfg.kv_lora_rank], axis=-1)
+    c = rmsnorm(p["kv_ln"], c, cfg.eps)
+    k_pe = apply_rope_interleaved(k_pe[:, :, None, :], positions,
+                                  mla_inv_freq(cfg))[:, :, 0]
+    return jnp.concatenate([c, k_pe.astype(c.dtype)], axis=-1)
+
+
+def mla_expand(p, lat, cfg: MLAConfig):
+    """Non-absorbed keys and values from cached latents [B, Sk, latent_dim]:
+    (k [B, Sk, H, q_head_dim], v [B, Sk, H, v_head_dim])."""
+    B, Sk, _ = lat.shape
+    H = cfg.n_heads
+    c, k_pe = jnp.split(lat, [cfg.kv_lora_rank], axis=-1)
+    k_nope = dense(p["uk"], c).reshape(B, Sk, H, cfg.qk_nope_head_dim)
+    v = dense(p["uv"], c).reshape(B, Sk, H, cfg.v_head_dim)
+    k_pe = jnp.broadcast_to(k_pe[:, :, None, :],
+                            (B, Sk, H, cfg.qk_rope_head_dim))
+    return jnp.concatenate([k_nope, k_pe.astype(k_nope.dtype)], axis=-1), v
+
+
+def mla_attention(p, x, cfg: MLAConfig, positions) -> jax.Array:
+    """Full-sequence (train / prefill) latent attention, non-absorbed:
+    per-head keys and values up-projected from the latents."""
+    B, S, _ = x.shape
+    q_nope, q_pe = mla_query(p, x, cfg, positions)
+    k, v = mla_expand(p, mla_latent(p, x, cfg, positions), cfg)
+    out = flash_attention(jnp.concatenate([q_nope, q_pe], axis=-1), k, v,
+                          causal=True, block=min(1024, S),
+                          scale=mla_softmax_scale(cfg))
+    out = out.reshape(B, S, cfg.n_heads * cfg.v_head_dim).astype(x.dtype)
+    return shard(dense(p["o"], out), "batch", "seq", "embed_act")
+
+
+def init_latent_pool(num_blocks: int, block_size: int, cfg: MLAConfig,
+                     dtype=jnp.bfloat16):
+    """The latent pool leaf ``{"lat": [num_blocks + 1, block_size,
+    pool_width]}``: one entry per token serves every head's key (its
+    latent_dim lanes) and value (the first ``kv_lora_rank``); the last
+    block is the trash block, as in :func:`init_kv_pool`.  The entry is
+    zero-padded to a multiple of 128 lanes: a TPU tiles the minor
+    dimension by 128, and with 576 lanes it lays the pool out transposed
+    (block positions minor), which the kernel's tiles and the per-token
+    writes do not match, so every step would relayout the whole pool."""
+    return {"lat": jnp.zeros((num_blocks + 1, block_size, cfg.pool_width),
+                             dtype)}
+
+
+def _pool_entry(lat, cfg: MLAConfig):
+    """Latents [..., latent_dim] as pool entries [..., pool_width]."""
+    pad = cfg.pool_width - cfg.latent_dim
+    return jnp.pad(lat, [(0, 0)] * (lat.ndim - 1) + [(0, pad)])
+
+
+def mla_prefill_paged(p, x, pool: dict, cfg: MLAConfig, row_table, len0,
+                      count, layer=None) -> tuple:
+    """Chunked prefill for ONE slot against the latent pool (see
+    :func:`attention_prefill_paged` for the chunk contract).
+
+    Attention is non-absorbed and streams the row's live blocks only: a
+    loop over ``ceil((len0 + count) / bs)`` table entries up-projects each
+    block's latents to per-head keys and values and folds them into an
+    online softmax, queries masked causally by position.
+    """
+    B, C, _ = x.shape
+    H = cfg.n_heads
+    idx = len0 + jnp.arange(C)
+    q_nope, q_pe = mla_query(p, x, cfg, idx[None])
+    lat_new = mla_latent(p, x, cfg, idx[None])[0]  # [C, latent_dim]
+    bs = pool["lat"].shape[-2]
+    trash = pool["lat"].shape[-3] - 1
+    W = row_table.shape[0]
+    within = jnp.arange(C) < count
+    phys = jnp.where(within, row_table[jnp.minimum(idx // bs, W - 1)], trash)
+    lat_pool = _at(pool["lat"], layer, phys, idx % bs).set(
+        _pool_entry(lat_new, cfg).astype(pool["lat"].dtype))
+    qf = jnp.concatenate([q_nope, q_pe], axis=-1)[0].astype(jnp.float32) \
+        * mla_softmax_scale(cfg)                         # [C, H, dq]
+
+    def block(j, carry):
+        m, l, acc = carry
+        lat = _blocks(lat_pool, layer, row_table[j])[None, :, :cfg.latent_dim]
+        k, v = mla_expand(p, lat.astype(x.dtype), cfg)   # [1, bs, H, .]
+        s = jnp.einsum("qhd,khd->hqk", qf, k[0].astype(jnp.float32))
+        kv_pos = j * bs + jnp.arange(bs)
+        s = jnp.where(kv_pos[None, None, :] <= idx[None, :, None], s, -1e30)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        pr = jnp.exp(s - m_new[..., None])
+        corr = jnp.exp(m - m_new)
+        acc = acc * corr[..., None] + jnp.einsum(
+            "hqk,khd->hqd", pr, v[0].astype(jnp.float32))
+        return m_new, l * corr + jnp.sum(pr, axis=-1), acc
+
+    live = jnp.minimum((len0 + count + bs - 1) // bs, W)
+    m0 = jnp.full((H, C), -1e30, jnp.float32)
+    _, l, acc = jax.lax.fori_loop(
+        0, live, block, (m0, jnp.zeros((H, C), jnp.float32),
+                         jnp.zeros((H, C, cfg.v_head_dim), jnp.float32)))
+    out = (acc / jnp.maximum(l[..., None], 1e-30)).transpose(1, 0, 2)
+    out = out.reshape(B, C, H * cfg.v_head_dim).astype(x.dtype)
+    return shard(dense(p["o"], out), "batch", "seq", "embed_act"), \
+        {"lat": lat_pool}
+
+
+def mla_absorbed_query(p, q_nope, q_pe, cfg: MLAConfig):
+    """Decode's query in latent space: ``[q_nope W_UK^T, q_pe]`` per head,
+    scaled by the softmax scale, f32 [B, H, latent_dim]."""
+    B, H = q_nope.shape[0], cfg.n_heads
+    uk = p["uk"]["w"].astype(jnp.float32).reshape(
+        cfg.kv_lora_rank, H, cfg.qk_nope_head_dim)
+    q_lat = jnp.einsum("bhd,chd->bhc", q_nope.reshape(B, H, -1).astype(
+        jnp.float32), uk)
+    q = jnp.concatenate([q_lat, q_pe.reshape(B, H, -1).astype(jnp.float32)],
+                        axis=-1)
+    return q * mla_softmax_scale(cfg)
+
+
+def mla_absorbed_out(p, o_lat, x, cfg: MLAConfig):
+    """Attention output from the latent context [B, H, kv_lora_rank]:
+    ``W_UV`` per head, then ``W_O``."""
+    B, H = o_lat.shape[0], cfg.n_heads
+    uv = p["uv"]["w"].astype(jnp.float32).reshape(
+        cfg.kv_lora_rank, H, cfg.v_head_dim)
+    out = jnp.einsum("bhc,chd->bhd", o_lat, uv)
+    out = out.reshape(B, 1, H * cfg.v_head_dim).astype(x.dtype)
+    return shard(dense(p["o"], out), "batch", None, "embed_act")
+
+
+def mla_decode_paged(p, x, pool: dict, cfg: MLAConfig, table, kv_lens,
+                     active, *, use_flash: bool = True,
+                     interpret: bool | None = None, layer=None) -> tuple:
+    """Single-token decode against the latent pool, absorbed: ``W_UK`` is
+    folded into the query and ``W_UV`` into the output, so attention runs
+    over the cached latents directly (one 576-wide entry per position for
+    all heads).  Shapes as :func:`attention_decode_paged`."""
+    from repro.kernels.flash_decode import ops as _fd
+
+    B = x.shape[0]
+    q_nope, q_pe = mla_query(p, x, cfg, kv_lens[:, None])
+    lat_new = mla_latent(p, x, cfg, kv_lens[:, None])[:, 0]
+    bs = pool["lat"].shape[-2]
+    trash = pool["lat"].shape[-3] - 1
+    W = table.shape[1]
+    blk = jnp.minimum(kv_lens // bs, W - 1)
+    phys = jnp.where(active, table[jnp.arange(B), blk], trash)
+    new_pool = {"lat": _at(pool["lat"], layer, phys, kv_lens % bs).set(
+        _pool_entry(lat_new, cfg).astype(pool["lat"].dtype))}
+    q = _pool_entry(mla_absorbed_query(p, q_nope, q_pe, cfg), cfg)
+    o_lat = _fd.flash_decode(q, new_pool, table, kv_lens + 1,
+                             use_flash=use_flash, interpret=interpret,
+                             v_width=cfg.kv_lora_rank, layer=layer)
+    return mla_absorbed_out(p, o_lat, x, cfg), new_pool
 
 
 # ---------------------------------------------------------------------------

@@ -8,6 +8,12 @@ all-to-all at the dispatch/combine boundaries.
 
 granite-moe (40e top-8), dbrx (16e top-4) and jamba (16e top-2) all run
 through this layer.
+
+DeepSeekMoE (deepseek-v2-lite) runs the drop-free expert share
+(:func:`moe_share`, selected by ``MoEConfig.held``): the router scores all
+``num_experts``, and the layer computes only the experts this chip holds,
+each over exactly the tokens routed to it, plus the shared experts for
+every token.
 """
 from __future__ import annotations
 
@@ -29,28 +35,47 @@ class MoEConfig:
     top_k: int
     capacity_factor: float = 1.25
     router_z_loss: float = 1e-3
+    # The drop-free expert share: ``held`` experts, ids ``held_from ..
+    # held_from + held - 1`` of ``num_experts``, live here (None: the
+    # capacity path over all of them).  ``n_shared`` experts of width
+    # ``d_ff`` see every token; the top-k weights are renormalised only
+    # with ``norm_topk`` and then scaled by ``routed_scale``.
+    held: int | None = None
+    held_from: int = 0
+    n_shared: int = 0
+    norm_topk: bool = True
+    routed_scale: float = 1.0
+
+    @property
+    def n_local(self) -> int:
+        """Experts whose weights this layer holds."""
+        return self.num_experts if self.held is None else self.held
 
 
 def init_moe(key, cfg: MoEConfig):
-    ks = jax.random.split(key, 4)
+    ks = jax.random.split(key, 5)
     scale_in = (1.0 / cfg.d_model) ** 0.5
     scale_out = (1.0 / cfg.d_ff) ** 0.5
+    E = cfg.n_local
     p = {
         "router": (jax.random.normal(ks[0], (cfg.d_model, cfg.num_experts))
                    * scale_in).astype(jnp.float32),
-        "gate": (jax.random.normal(ks[1], (cfg.num_experts, cfg.d_model, cfg.d_ff))
+        "gate": (jax.random.normal(ks[1], (E, cfg.d_model, cfg.d_ff))
                  * scale_in).astype(jnp.float32),
-        "up": (jax.random.normal(ks[2], (cfg.num_experts, cfg.d_model, cfg.d_ff))
+        "up": (jax.random.normal(ks[2], (E, cfg.d_model, cfg.d_ff))
                * scale_in).astype(jnp.float32),
-        "down": (jax.random.normal(ks[3], (cfg.num_experts, cfg.d_ff, cfg.d_model))
+        "down": (jax.random.normal(ks[3], (E, cfg.d_ff, cfg.d_model))
                  * scale_out).astype(jnp.float32),
     }
     lg = {
-        "router": ("embed", "experts"),
+        "router": ("embed", None if cfg.held is not None else "experts"),
         "gate": ("experts", "embed", "mlp"),
         "up": ("experts", "embed", "mlp"),
         "down": ("experts", "mlp", "embed"),
     }
+    if cfg.n_shared:
+        p["shared"], lg["shared"] = L.init_swiglu(
+            ks[4], cfg.d_model, cfg.n_shared * cfg.d_ff)
     return p, lg
 
 
@@ -133,13 +158,69 @@ def _batch_manual(fn, n_out: int):
                          axis_names=set(axes), check_vma=False)
 
 
+def route(p, x: jax.Array, cfg: MoEConfig) -> tuple:
+    """Softmax router over all ``num_experts`` in f32, greedy top-k:
+    (probs [.., E], top_p [.., K] weights as :func:`moe_share` applies
+    them, top_e [.., K] expert ids)."""
+    logits = x.astype(jnp.float32) @ p["router"].astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk:
+        top_p = top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20)
+    return probs, top_p * cfg.routed_scale, top_e
+
+
+def moe_share(p, x: jax.Array, cfg: MoEConfig) -> tuple:
+    """The drop-free expert share: x [B, S, d] -> (y, aux).
+
+    Each of the ``cfg.held`` experts here computes exactly the tokens
+    routed to it (``ragged_dot`` over the token-expert picks sorted by
+    held expert; picks of experts held elsewhere sort last and take no
+    part), weighted by its router weight; the shared experts add their
+    MLP for every token.  A token that picked no held expert gets the
+    shared part only: what the absent experts would add is computed by
+    the chips that hold them.  ``aux["held_picks"]`` [B, S] counts each
+    token's picks that landed here.
+    """
+    B, S, d = x.shape
+    T, K, E = B * S, cfg.top_k, cfg.held
+    xt = x.reshape(T, d)
+    probs, top_p, top_e = route(p, xt, cfg)
+    local = top_e - cfg.held_from
+    here = (local >= 0) & (local < E)
+    group = jnp.where(here, local, E).reshape(T * K)
+    order = jnp.argsort(group, stable=True)
+    tok = order // K
+    sizes = jnp.sum(jax.nn.one_hot(group, E, dtype=jnp.int32), axis=0)
+    xs = jnp.take(xt, tok, axis=0)
+    dt = x.dtype
+    h = jax.nn.silu(jax.lax.ragged_dot(xs, p["gate"].astype(dt), sizes)) \
+        * jax.lax.ragged_dot(xs, p["up"].astype(dt), sizes)
+    out = jax.lax.ragged_dot(h, p["down"].astype(dt), sizes)
+    w = jnp.where(here, top_p, 0.0).reshape(T * K)[order]
+    contrib = jnp.where((w > 0)[:, None], out.astype(jnp.float32) * w[:, None],
+                        0.0)
+    y = jnp.zeros((T, d), jnp.float32).at[tok].add(contrib)
+    if cfg.n_shared:
+        y = y + L.swiglu(p["shared"], xt[None])[0].astype(jnp.float32)
+    me = jnp.mean(jax.nn.one_hot(top_e[..., 0], cfg.num_experts), axis=0)
+    aux = {"load_balance": cfg.num_experts * jnp.sum(
+               me * jnp.mean(probs, axis=0)),
+           "router_z": jnp.float32(0.0), "dropped_frac": jnp.float32(0.0),
+           "held_picks": jnp.sum(here, axis=-1).reshape(B, S)}
+    return y.astype(dt).reshape(B, S, d), aux
+
+
 def moe(p, x: jax.Array, cfg: MoEConfig) -> tuple:
     """x: [B, S, d] -> (y [B, S, d], aux_losses dict).
 
     Routing is PER BATCH ROW and shard_mapped over the data axes (see
     _batch_manual); capacity is per-row: cap = S * k * cf / E.  The expert
     einsums stay under GSPMD with experts sharded over `model` (EP).
+    A config with ``held`` set runs :func:`moe_share` instead.
     """
+    if cfg.held is not None:
+        return moe_share(p, x, cfg)
     B, S, d = x.shape
     E, K = cfg.num_experts, cfg.top_k
     B_out = B  # output batch (fold-restored by _combine_local)

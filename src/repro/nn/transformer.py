@@ -13,6 +13,11 @@ compile times).  Remat wraps the period body for training.
 
 Three entry points per model: `forward` (train / prefill), `decode_step`
 (one token against mutable caches), `loss_fn` (next-token CE + MoE aux).
+
+Latent-attention stacks (``mla`` set: DeepSeek-V2) may lead with
+``first_dense`` dense ``attn_mlp`` layers of width ``d_ff`` that sit
+outside the scan (``params["lead"]``); the ``block_pattern`` periods follow.
+They serve from the paged latent pool only (:mod:`repro.lm.model`).
 """
 from __future__ import annotations
 
@@ -60,6 +65,8 @@ class ModelConfig:
     mamba: Mb.MambaConfig | None = None
     xlstm: Xl.XLSTMConfig | None = None
     encoder: EncoderConfig | None = None  # whisper
+    mla: L.MLAConfig | None = None  # latent attention in every attn block
+    first_dense: int = 0  # leading dense attn_mlp layers, outside the scan
     tie_embeddings: bool = False
     remat: bool = True
     remat_policy: str = "full"  # full = recompute everything in the period;
@@ -74,8 +81,9 @@ class ModelConfig:
 
     @property
     def n_periods(self) -> int:
-        assert self.n_layers % self.period == 0, (self.n_layers, self.period)
-        return self.n_layers // self.period
+        n = self.n_layers - self.first_dense
+        assert n % self.period == 0, (self.n_layers, self.period)
+        return n // self.period
 
     def attn_cfg(self, causal=True) -> L.AttnConfig:
         return L.AttnConfig(self.d_model, self.n_heads, self.n_kv_heads,
@@ -93,7 +101,10 @@ def _init_block(key, kind: str, cfg: ModelConfig):
     norm_init = L.init_rmsnorm if cfg.norm == "rmsnorm" else L.init_layernorm
     if kind.startswith("attn"):
         p["ln1"], lg["ln1"] = norm_init(cfg.d_model)
-        p["attn"], lg["attn"] = L.init_attention(ks[0], cfg.attn_cfg())
+        if cfg.mla is not None:
+            p["attn"], lg["attn"] = L.init_mla(ks[0], cfg.mla)
+        else:
+            p["attn"], lg["attn"] = L.init_attention(ks[0], cfg.attn_cfg())
         if "cross" in kind:
             p["lnx"], lg["lnx"] = norm_init(cfg.d_model)
             xcfg = L.AttnConfig(cfg.d_model, cfg.n_heads, cfg.n_heads, causal=False)
@@ -158,6 +169,14 @@ def _init_tree(key: jax.Array, cfg: ModelConfig):
     logical["blocks"] = jax.tree.map(lambda lgx: ("layers",) + lgx,
                                      box["logical"],
                                      is_leaf=lambda x: isinstance(x, tuple))
+    if cfg.first_dense:
+        params["lead"], logical["lead"] = [], []
+        for _ in range(cfg.first_dense):
+            key, kb = jax.random.split(key)
+            bp, blg = _init_block(kb, "attn_mlp", cfg)
+            params["lead"].append(
+                jax.tree.map(lambda x: x.astype(cfg.param_dtype), bp))
+            logical["lead"].append(blg)
 
     if cfg.encoder is not None:
         e = cfg.encoder
@@ -214,7 +233,11 @@ def _apply_block(p, kind: str, cfg: ModelConfig, x, positions, enc_out,
     new_cache = cache
     if kind.startswith("attn"):
         h = _norm(cfg, p["ln1"], x)
-        if decode:
+        if cfg.mla is not None:
+            if decode:
+                raise ValueError(contiguous_unsupported_reason(cfg))
+            a = L.mla_attention(p["attn"], h, cfg.mla, positions)
+        elif decode:
             a, new_cache = L.attention_decode(p["attn"], h, cache["self"],
                                               cfg.attn_cfg(), positions)
             new_cache = {**cache, "self": new_cache}
@@ -301,6 +324,10 @@ def forward(params, cfg: ModelConfig, tokens, positions=None, vision_embeds=None
         if cfg.encoder is not None else None
     aux_acc = {"load_balance": 0.0, "router_z": 0.0, "dropped_frac": 0.0}
 
+    for lead in params.get("lead", ()):
+        x, _, _ = _apply_block(lead, "attn_mlp", cfg, x, positions, None,
+                               None, False)
+
     def period_body(x, period_params):
         auxes = {}
         for bi, kind in enumerate(cfg.block_pattern):
@@ -358,8 +385,20 @@ def loss_fn(params, cfg: ModelConfig, batch: dict):
 # Decode
 # ---------------------------------------------------------------------------
 
+def contiguous_unsupported_reason(cfg: ModelConfig) -> str | None:
+    """None when the contiguous cache can serve ``cfg``, else the reason."""
+    if cfg.mla is not None:
+        return ("latent attention (MLA) is served from the paged latent "
+                "pool only (ServeEngine(paged=PagedConfig(...))); the "
+                "contiguous cache holds per-head K/V")
+    return None
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
     """Stacked per-period caches mirroring the block pattern."""
+    reason = contiguous_unsupported_reason(cfg)
+    if reason is not None:
+        raise ValueError(reason)
     if cfg.kv_cache_dtype == "int8":
         dtype = jnp.int8
     per = []
@@ -482,7 +521,8 @@ def count_params_cfg(cfg: ModelConfig) -> tuple:
     moe_total = 0
     for path, leaf in jax.tree_util.tree_flatten_with_path(shapes)[0]:
         total += leaf.size
-        if any("moe" == getattr(k, "key", None) for k in path):
+        keys = [getattr(k, "key", None) for k in path]
+        if "moe" in keys and "shared" not in keys:
             name = getattr(path[-1], "key", "")
             if name in ("gate", "up", "down"):
                 moe_total += leaf.size

@@ -30,8 +30,14 @@ prefill/decode boundary like any other stage boundary.
 Retirement is at burst granularity (like the factorizer engine's sweep
 bursts): a slot may overshoot its stop condition by up to
 ``decode_per_step - 1`` tokens; the finished request's ``tokens`` are
-trimmed to ``max_new_tokens`` / first EOS, and a slot parked by the device
-layer's KV-capacity guard retires with ``truncated=True``.
+trimmed to ``max_new_tokens`` / first EOS (``logits`` holds each kept
+token's f32 logit), and a slot parked by the device layer's KV-capacity
+guard retires with ``truncated=True``.  On the paged pool a request is
+admitted only when the blocks it can reach (prompt, ``max_new_tokens`` and
+one burst's overshoot) are free beyond those the live slots may still
+claim, so a pool that admits never parks a slot mid-generation.
+``sweeps_total`` counts decode steps over the slot batch, the LM's
+counterpart of a resonator sweep.
 """
 from __future__ import annotations
 
@@ -40,7 +46,7 @@ import os
 from collections import deque
 from typing import Any
 
-import jax.numpy as jnp
+import numpy as np
 
 from repro import obs as obs_mod
 from repro.cogsim import model as hw_model
@@ -49,8 +55,9 @@ from repro.engine import registry
 from repro.engine.engine import (LAT_WINDOW_CAP, derive_sweeps_per_step,
                                  rolling_latency_ms, step_unit_ops)
 from repro.launch.serve import ServeEngine
-from repro.lm.paging import PagedConfig
+from repro.lm.paging import PagedConfig, cdiv
 from repro.lm.sampling import SamplingSpec
+from repro.nn import transformer as T
 
 
 @dataclasses.dataclass
@@ -65,6 +72,7 @@ class LMRequest:
     sampling: SamplingSpec | None = None  # None = greedy
     priority: int = 0  # queue order: lower serves first (fleet classes)
     tokens: list = dataclasses.field(default_factory=list)  # generated ids
+    logits: list = dataclasses.field(default_factory=list)  # their f32 logits
     result: Any = None  # {"tokens": ..., "text_len": ...} convenience dict
     truncated: bool = False  # KV capacity parked the slot before a stop
     done_time: float | None = None
@@ -75,9 +83,13 @@ class LMRequest:
             self.done_time - self.submit_time
 
 
-def _resolve_paged(paged) -> PagedConfig | None:
+def _resolve_paged(paged, cfg) -> PagedConfig | None:
     if paged is None:
-        return PagedConfig() if os.environ.get("REPRO_LM_PAGED") else None
+        # stacks the contiguous cache cannot hold (latent attention) serve
+        # paged whatever the environment says
+        paged_only = T.contiguous_unsupported_reason(cfg) is not None
+        return PagedConfig() if paged_only or os.environ.get(
+            "REPRO_LM_PAGED") else None
     if paged is True:
         return PagedConfig()
     if paged is False:
@@ -101,7 +113,7 @@ class LMEngine:
         self.cfg, self.hw = cfg, hw
         self.slots = slots
         self.eos_id = eos_id
-        self.paged = _resolve_paged(paged)
+        self.paged = _resolve_paged(paged, cfg)
         self._prompt_len_hint = prompt_len_hint
         self._dps_pinned = decode_per_step is not None
         # Observability seam, mirroring Engine: spans/counters around the
@@ -124,6 +136,7 @@ class LMEngine:
         self.completed: dict = {}
         self.completed_total = 0  # all-time (runtime may evict `completed`)
         self.steps_total = 0
+        self.sweeps_total = 0  # decode steps over the slot batch
         self.tokens_total = 0
         self.recoveries_total = 0
         self.resizes_total = 0
@@ -181,7 +194,9 @@ class LMEngine:
         per-request seed makes replay after recover/resize bit-equal.
         ``priority`` orders the queue (lower serves first; FIFO within a
         priority)."""
-        prompt = jnp.asarray(prompt, jnp.int32)
+        # host-side ids: slicing a device array of a new length would
+        # compile a program per prompt length
+        prompt = np.asarray(prompt, np.int32)
         if prompt.ndim != 1 or prompt.shape[0] == 0:
             raise ValueError("submit expects a non-empty 1-D token prompt")
         if prompt.shape[0] > self.serve.slot_capacity:
@@ -220,11 +235,32 @@ class LMEngine:
             # paged: a drained pool defers admission (priority order
             # preserved — the BEST candidate parks) until retiring slots
             # release blocks — parking, not rejection
-            if not self.serve.can_admit(int(req.prompt.shape[0])):
+            if not self.serve.can_admit(int(req.prompt.shape[0]),
+                                        self._claims(req)):
                 break
             del self._queue[i]
             self._owner[slot] = req
             self.serve.add_request(slot, req.prompt, sampling=req.sampling)
+
+    def _reach_blocks(self, req: LMRequest) -> int:
+        """Pool blocks a request can grow to: its prompt, every token it may
+        generate and one burst's overshoot (at most the whole pool)."""
+        serve = self.serve
+        tokens = min(int(req.prompt.shape[0]) + req.max_new_tokens
+                     + self.decode_per_step, serve.slot_capacity)
+        return min(cdiv(tokens, self.paged.block_size),
+                   serve.blocks.num_blocks)
+
+    def _claims(self, req: LMRequest) -> int:
+        """Blocks the live slots may still take, plus what admitting ``req``
+        needs beyond its prompt: admission leaves them free."""
+        if self.paged is None:
+            return 0
+        rows = self.serve.blocks.rows
+        live = sum(max(0, self._reach_blocks(r) - len(rows[s]))
+                   for s, r in enumerate(self._owner) if r is not None)
+        prompt = cdiv(int(req.prompt.shape[0]), self.paged.block_size)
+        return live + max(0, self._reach_blocks(req) - prompt)
 
     def _stop_at(self, req: LMRequest, produced: list) -> int | None:
         """Index (exclusive) to trim `produced` at, or None if not done."""
@@ -247,8 +283,10 @@ class LMEngine:
                 continue
             req.truncated = stop is None  # parked at KV capacity
             req.tokens = produced[:stop] if stop is not None else produced
+            req.logits = self.serve.generated_logits[slot][:len(req.tokens)]
             req.done_time = self._clock()
-            req.result = {"tokens": req.tokens, "truncated": req.truncated}
+            req.result = {"tokens": req.tokens, "logits": req.logits,
+                          "truncated": req.truncated}
             self.tokens_total += len(req.tokens)
             self.completed[req.id] = req
             self.completed_total += 1
@@ -267,21 +305,31 @@ class LMEngine:
         with obs.span("step", track=self.obs_track, cat="engine") as sp:
             with obs.span("fill", track=self.obs_track, cat="engine"):
                 self._fill()
-            if all(o is None for o in self._owner):
+            live = sum(o is not None for o in self._owner)
+            if not live:
                 return []
+            serve = self.serve
+            held0 = serve.held_picks_total
             with obs.span("decode-burst", track=self.obs_track,
                           cat="engine") as bp:
-                n = 0
+                n = kv_tokens = 0
                 for _ in range(self.decode_per_step):
                     # every live slot parked at capacity ends the burst early
-                    if self.serve.step() is None:
+                    if serve.step() is None:
                         break
                     n += 1
+                    if obs.enabled:  # cached positions this step read
+                        kv_tokens += int(serve.lens[serve.active].sum())
             self.steps_total += 1
+            self.sweeps_total += n
             with obs.span("retire", track=self.obs_track, cat="engine"):
                 finished = self._retire()
         if obs.enabled:
-            bp.args["decodes"] = n
+            bp.args.update(live=live, slots=self.slots, steps=n,
+                           kv_tokens=kv_tokens)
+            if serve.held_experts:
+                bp.args.update(held_picks=serve.held_picks_total - held0,
+                               held_experts=serve.held_experts)
             sp.args.update(decodes=n, retired=len(finished))
             obs.count("steps", 1, engine=self.obs_track)
             obs.count("decode_steps", n, engine=self.obs_track)

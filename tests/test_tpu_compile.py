@@ -92,6 +92,22 @@ def test_flash_decode_compiles_for_v5e(one_chip, kv_dtype):
     assert has_tpu_kernel(compiled)
 
 
+@pytest.mark.parametrize("layered", [False, True])
+def test_mla_decode_compiles_for_v5e(one_chip, layered):
+    # deepseek-v2-lite: 16 heads over one 576-wide latent (512 value lanes),
+    # 512-position blocks, 32 slots of up to 18 blocks; layered reads one
+    # layer of the 26-layer stacked pool in place
+    B, H, Dk, dv, bs, W = 32, 16, 576, 512, 512, 18
+    pool = ((26, 65, bs, Dk) if layered else (65, bs, Dk), BF16)
+    layer = ((), I32) if layered else None
+    fn = lambda q, k, t, ln, *lay: fdk.flash_decode(  # noqa: E731
+        q, k, None, t, ln, v_width=dv, layer=lay[0] if lay else None)
+    compiled = _compile(fn, one_chip, ((B, H, Dk), F32), pool, ((B, W), I32),
+                        ((B,), I32), *([layer] if layer else []))
+    assert has_tpu_kernel(compiled)
+    assert "mla_decode" in compiled.as_text()
+
+
 @pytest.mark.parametrize("n", [1, 16])
 def test_similarity_int8_compiles_for_v5e(one_chip, n):
     M, D = 10, 1024
