@@ -112,8 +112,8 @@ class Request:
     """One submitted reasoning request (1..k queries slotted independently)."""
 
     id: int
-    queries: jax.Array  # [k, D]
-    keys: jax.Array  # [k, ...] one PRNG key per query
+    queries: np.ndarray  # [k, D] float32, on the host until a slot takes it
+    keys: np.ndarray  # [k, ...] one PRNG key per query, on the host
     meta: Any
     submit_time: float
     submit_sweep: int
@@ -276,10 +276,14 @@ class Engine:
         priority).  ``max_iters`` caps this request's resonator iteration
         budget below ``cfg.max_iters`` — the fleet controller's brownout
         trim: rows retire at the cap with whatever estimate they reached.
+
+        Queries and keys stay on the host until :meth:`_fill` slots them,
+        as host copies: a device array is pulled once here, and the caller
+        may reuse its buffers.
         """
         if max_iters is not None and max_iters < 1:
             raise ValueError(f"max_iters must be >= 1, got {max_iters}")
-        queries = jnp.asarray(queries)
+        queries = np.array(queries, np.float32)
         if queries.ndim == 1:
             queries = queries[None]
         k = queries.shape[0]
@@ -287,7 +291,7 @@ class Engine:
             if key is None:
                 self._key, key = jax.random.split(self._key)
             keys = jax.random.split(key, k)
-        req = Request(self._next_id, queries, jnp.asarray(keys), meta,
+        req = Request(self._next_id, queries, np.array(keys), meta,
                       self._clock(), self.sweeps_total,
                       priority=int(priority), iter_budget=max_iters)
         req.rows = [None] * k
@@ -323,28 +327,26 @@ class Engine:
                     continue
                 req, qi = self._pop_next()
                 self._owner[slot] = (req, qi)
-                fills.append((slot, req.queries[qi], req.keys[qi]))
+                fills.append((slot, req, qi))
             if sp is not None:
                 sp.args["rows"] = len(fills)
             if not fills:
                 return
             # ONE fixed-shape jitted scatter for however many slots freed
             # up: indices pad with `slots` (out of range -> dropped), so
-            # every fill count reuses the same compiled program.  The padded
-            # batch is assembled host-side — eager jnp.stack over a varying
-            # fill count would compile a fresh concatenate per distinct
-            # count.
+            # every fill count reuses the same compiled program.  Rows are
+            # host arrays (see submit), copied into the padded host batch
+            # with no device op per row; the batch goes up with the call.
             idx = np.full(self.slots, self.slots, np.int32)
             new_qs = np.zeros((self.slots, self.spec.dim), np.float32)
-            keys = np.zeros((self.slots,) + fills[0][2].shape,
-                            np.asarray(fills[0][2]).dtype)
-            for j, (slot, q, k) in enumerate(fills):
+            keys0 = fills[0][1].keys
+            keys = np.zeros((self.slots,) + keys0.shape[1:], keys0.dtype)
+            for j, (slot, req, qi) in enumerate(fills):
                 idx[j] = slot
-                new_qs[j] = np.asarray(q)
-                keys[j] = np.asarray(k)
+                new_qs[j] = req.queries[qi]
+                keys[j] = req.keys[qi]
             self.qs, self.state = self._refill_many(
-                self.qs, self.state, jnp.asarray(idx), jnp.asarray(new_qs),
-                jnp.asarray(keys))
+                self.qs, self.state, idx, new_qs, keys)
 
     def _retire(self) -> list:
         """Retire ripe rows in three sibling spans: ``retire`` (the

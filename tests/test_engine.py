@@ -308,6 +308,97 @@ def test_per_request_iterations_match_solo_runs(lvrf_setup):
                                    float(solo.reconstruction_sim), rtol=1e-6)
 
 
+@pytest.mark.parametrize("as_device", [False, True], ids=["numpy", "jax"])
+@pytest.mark.parametrize("pipeline", ["nvsa_abduction", "lvrf_rows"])
+def test_host_rows_retire_bit_equal_solo(pipeline, as_device):
+    """Rows kept on the host from submit to fill retire with the solo
+    factorize(q, key) trajectory, whether submitted as numpy or jax.Array,
+    for both registered factorizer workloads.  Scores match to float32
+    rounding only: on the CPU a batch of one and the slot batch reduce in
+    another order (~2e-5 relative)."""
+    spec = engine.registry.build(pipeline, jax.random.PRNGKey(0))
+    n_q = 5
+    rng = np.random.default_rng(3)
+    F, M = spec.codebooks.shape[:2]
+    sizes = (np.full(F, M) if spec.valid_mask is None
+             else np.asarray(spec.valid_mask).sum(-1))
+    attrs = jnp.asarray(rng.integers(0, sizes, (n_q, F)))
+    qs = np.asarray(fz.bind_combo(spec.codebooks, attrs, spec.cfg.vsa))
+    qs = (qs + 0.4 * qs.std() * rng.normal(size=qs.shape)).astype(np.float32)
+    keys = jax.random.split(jax.random.PRNGKey(9), n_q)
+    if not as_device:
+        keys = np.asarray(keys)
+    eng = engine.Engine(spec, slots=3, sweeps_per_step=2)
+    ids = [eng.submit(jnp.asarray(qs[i]) if as_device else qs[i],
+                      keys=keys[i:i + 1]) for i in range(n_q)]
+    done = {r.id: r for r in eng.drain()}
+    for i, rid in enumerate(ids):
+        got = done[rid].factorization
+        solo = fz.factorize(jnp.asarray(qs[i]), spec.codebooks,
+                            jnp.asarray(keys[i]), spec.cfg, spec.valid_mask)
+        np.testing.assert_array_equal(got.indices[0], np.asarray(solo.indices))
+        assert int(got.iterations[0]) == int(solo.iterations)
+        assert bool(got.converged[0]) == bool(solo.converged)
+        np.testing.assert_allclose(got.scores[0], np.asarray(solo.scores),
+                                   rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("keys_from", ["caller", "engine"])
+@pytest.mark.parametrize("as_device", [False, True], ids=["numpy", "jax"])
+def test_submit_keeps_rows_on_host(lvrf_setup, as_device, keys_from):
+    """A request's queries and keys are host arrays from submit on, whether
+    the caller sent numpy or jax.Array and whether the engine derived the
+    keys itself."""
+    spec, _, _ = lvrf_setup
+    qs = np.random.default_rng(0).normal(size=(3, spec.dim))
+    keys = jax.random.split(jax.random.PRNGKey(1), 3)
+    eng = engine.Engine(spec, slots=4, sweeps_per_step=1)
+    kw = {"keys": keys if as_device else np.asarray(keys)} \
+        if keys_from == "caller" else {}
+    eng.submit(jnp.asarray(qs) if as_device else qs, **kw)
+    req = eng._queue[0][0]
+    assert type(req.queries) is np.ndarray and type(req.keys) is np.ndarray
+    assert req.queries.dtype == np.float32 and req.queries.shape == (3,
+                                                                     spec.dim)
+    assert req.keys.shape == (3, 2)
+    if keys_from == "caller":
+        np.testing.assert_array_equal(req.keys, np.asarray(keys))
+
+
+def test_fill_makes_no_device_op_per_row(lvrf_setup, monkeypatch):
+    """One fill of 100 host rows into 128 slots makes no device slice and no
+    device pull, and exactly one refill call."""
+    spec, _, _ = lvrf_setup
+    eng = engine.Engine(spec, slots=128, sweeps_per_step=1)
+    rows = np.random.default_rng(0).normal(size=(101, spec.dim))
+    eng.submit(rows[:1])
+    eng._fill()  # compiles the refill program outside the counted fill
+    eng.submit(rows[1:])
+    refills = []
+    refill = eng._refill_many
+    eng._refill_many = lambda *a: refills.append(a) or refill(*a)
+    array_t = type(eng.qs)
+    calls = {"__getitem__": 0, "__array__": 0, "__buffer__": 0}
+
+    def counted(name):
+        orig = getattr(array_t, name)
+
+        def f(self, *a, **k):
+            calls[name] += 1
+            return orig(self, *a, **k)
+        return f
+
+    for name in calls:
+        monkeypatch.setattr(array_t, name, counted(name))
+    eng._fill()
+    monkeypatch.undo()
+    assert calls == {"__getitem__": 0, "__array__": 0, "__buffer__": 0}
+    assert len(refills) == 1
+    assert sum(o is not None for o in eng._owner) == 101
+    np.testing.assert_array_equal(np.asarray(eng.qs)[1:101],
+                                  rows[1:].astype(np.float32))
+
+
 def test_sweeps_per_step_is_scheduler_derived(lvrf_setup):
     spec, _, _ = lvrf_setup
     k = engine.derive_sweeps_per_step(spec, slots=16)

@@ -515,9 +515,12 @@ def _overload_run(spec, good, junk, gkeys, jkeys, fleet, target_s):
                         "best_effort": obs.SLOTarget(target_s)},
                    fleet=fleet)
     r.register("lvrf", eng)
+    # the junk is staged before the stepper starts, so its first pass
+    # ingests all of it: submitted while the stepper runs, the first junk
+    # rows could burn out before the last junk request is ingested
+    jids = [r.submit("lvrf", junk[i], keys=jkeys[i][None],
+                     class_="best_effort") for i in range(N_JUNK)]
     with r:
-        jids = [r.submit("lvrf", junk[i], keys=jkeys[i][None],
-                         class_="best_effort") for i in range(N_JUNK)]
         # the interactive minority must arrive while the best-effort bulk
         # actually owns the engine: every junk request ingested, all four
         # slots held by live junk rows mid-burn
