@@ -22,11 +22,11 @@ def main() -> int:
     from repro.launch.programs import enable_compile_cache
     enable_compile_cache()
     from benchmarks import (engine_serve, engine_sharded, factorizer_batch,
-                            fault_recovery, kernels_micro, lm_serve,
-                            paper_hardware, paper_tables, runtime_serve)
+                            fault_recovery, kernels_micro, paper_hardware,
+                            paper_tables, runtime_serve)
 
     mods = [paper_hardware, kernels_micro, paper_tables, engine_serve,
-            engine_sharded, runtime_serve, lm_serve, fault_recovery]
+            engine_sharded, runtime_serve, fault_recovery]
     # the vmap-of-scalar baseline leg costs minutes in interpret mode, so the
     # factorizer comparison only runs when asked for (it also has its own
     # __main__ entry that writes BENCH_factorizer.json)

@@ -238,8 +238,8 @@ def _warm(engines, problems) -> None:
         e.completed.clear()
         if name == "lm":
             e.steps_total = e.tokens_total = 0
-            e.serve.prefill_dispatches = e.serve.decode_dispatches = 0
-            e.serve.kv_bytes_touched = 0
+            e.prefill_dispatches = e.decode_dispatches = 0
+            e.kv_bytes_touched = 0
         else:
             e.sweeps_total = e.steps_total = 0
 
@@ -380,13 +380,13 @@ def structural_counters(engines: dict) -> dict:
     """The gated (deterministic, transferable) counters per engine."""
     out = {}
     for name, e in engines.items():
-        if hasattr(e, "serve"):  # LMEngine
+        if e.engine_kind == "lm":
             out[name] = {
                 "steps": e.steps_total,
                 "tokens_total": e.tokens_total,
-                "prefill_dispatches": e.serve.prefill_dispatches,
-                "decode_dispatches": e.serve.decode_dispatches,
-                "kv_bytes_touched": e.serve.kv_bytes_touched,
+                "prefill_dispatches": e.prefill_dispatches,
+                "decode_dispatches": e.decode_dispatches,
+                "kv_bytes_touched": e.kv_bytes_touched,
                 "units_per_step": e.decode_per_step,
             }
         else:

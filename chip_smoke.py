@@ -50,7 +50,7 @@ from repro.data import raven  # noqa: E402
 from repro.kernels import resolve_interpret  # noqa: E402
 from repro.launch import programs  # noqa: E402
 from repro.launch.mesh import make_host_mesh  # noqa: E402
-from repro.launch.serve import ServeEngine  # noqa: E402
+from repro.lm.kv import ContiguousKV, PagedKV  # noqa: E402
 from repro.lm.paging import PagedConfig  # noqa: E402
 from repro.models import cnn, lvrf, nvsa  # noqa: E402
 from repro.nn import transformer as T  # noqa: E402
@@ -282,16 +282,18 @@ def lm_phase(seed: int, cfg=None, prompts: int = 4, prompt_len: int = 64,
           "lm: a request stopped short of its new-token budget")
 
     # The same first decode step on both KV layouts, same weights.
-    layouts = {"paged": ServeEngine(cfg, params, prompts, max_len,
-                                    paged=paged),
-               "contiguous": ServeEngine(cfg, params, prompts, max_len)}
-    for eng in layouts.values():
+    host = np.asarray(toks, np.int32)
+    last, active = host[:, -1:], np.ones(prompts, bool)
+    lens = np.full(prompts, prompt_len - 1)
+    pg = PagedKV(cfg, params, prompts, max_len, paged)
+    ct = ContiguousKV(cfg, params, prompts, max_len)
+    first = {}
+    for name, kv in (("paged", pg), ("contiguous", ct)):
         for s in range(prompts):
-            eng.add_request(s, toks[s])
-        eng.step()
-    pg, ct = layouts["paged"], layouts["contiguous"]
-    got = np.asarray(pg.last_logits[:, -1], np.float32)
-    ref = np.asarray(ct.last_logits[:, -1], np.float32)
+            kv.prefill(s, host[s])
+        first[name] = np.asarray(kv.decode(last, lens, active)[0][:, -1],
+                                 np.float32)
+    got, ref = first["paged"], first["contiguous"]
     check(bool(np.isfinite(got).all() and np.isfinite(ref).all()),
           "lm: non-finite logits")
     rel = float(np.max(np.abs(got - ref)) / max(np.max(np.abs(ref)), 1e-9))
@@ -301,10 +303,10 @@ def lm_phase(seed: int, cfg=None, prompts: int = 4, prompt_len: int = 64,
     check([int(t[0]) for t in tokens] == got.argmax(-1).tolist(),
           "lm: served first tokens are not the argmax of the paged logits")
     kernel = has_kernel(
-        pg._decode_paged, params, pg.pool, jnp.asarray(pg.blocks.table()),
-        jnp.asarray(pg.lens, jnp.int32), jnp.zeros((prompts, 1), jnp.int32),
-        jnp.asarray(pg.active))
-    expected = kernel_expected(paged.use_flash)
+        pg._decode, params, pg.pool, jnp.asarray(pg.blocks.table()),
+        jnp.asarray(lens, jnp.int32), jnp.zeros((prompts, 1), jnp.int32),
+        jnp.asarray(active))
+    expected = kernel_expected(True)
     check(kernel == expected,
           f"lm: paged decode tpu_custom_call present={kernel}, "
           f"expected {expected}")

@@ -2,8 +2,8 @@
 
 Three very differently shaped engines behind the same async ``Runtime``:
 NVSA RPM abduction (unitary block-code factorization), LVRF row decoding
-(bipolar MAP), and transformer greedy decode (the ``lm_decode`` adapter over
-``launch/serve.ServeEngine``).  Requests are submitted from the caller
+(bipolar MAP), and transformer greedy decode (``LMEngine`` over a KV
+layout of ``repro.lm.kv``).  Requests are submitted from the caller
 thread and complete on the background stepper, which picks the next engine
 by adSCH-modeled step cost x queue depth; the LVRF engine additionally opts
 into EWMA-driven slot re-tuning — watch its ``slots`` change mid-run with

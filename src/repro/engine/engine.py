@@ -1,6 +1,6 @@
 """Request-level serving engine: continuous batching of reasoning queries.
 
-The symbolic analogue of LM decode slotting (launch/serve.py): the factorizer
+The symbolic analogue of LM decode slotting (runtime/lm.py): the factorizer
 state is a fixed-shape ``[N, F, D]`` batch riding ONE while_loop program, and
 incoming factorization requests are slotted into rows as converged rows
 retire — so the batch never drains to the slowest query the way a

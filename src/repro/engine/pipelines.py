@@ -9,7 +9,7 @@ value atoms (F=3, M=n_values, D=2048, deterministic).  The engine sees both
 as ServeSpecs; nothing in :mod:`repro.engine.engine` is NVSA-shaped.
 
 ``lm_decode`` is the third kind of workload: transformer serving
-(`launch/serve.ServeEngine`'s prefill/decode) re-expressed as a registered
+(`runtime/lm.LMEngine`'s prefill/decode) re-expressed as a registered
 StageGraph + ``step_ops`` so the SAME adSCH machinery
 (:func:`repro.engine.build.plan_interleave`,
 :func:`repro.engine.engine.derive_sweeps_per_step`) prices LM steps; the

@@ -1,6 +1,6 @@
 """Public dispatch for paged flash-decode attention (kernel vs reference).
 
-The serving stack (``repro.lm`` model functions -> ``launch/serve``) calls
+The serving stack (``repro.lm`` model functions -> ``lm/kv.PagedKV``) calls
 :func:`flash_decode` with a KV *pool* dict and a block table; ``use_flash``
 selects the Pallas online-softmax kernel or the dense gathered reference,
 ``interpret=None`` resolves to interpret mode off-TPU (the CPU CI path) —

@@ -3,10 +3,11 @@
 The device side is a fixed pool of ``[num_blocks + 1, block_size, G, dh]``
 KV blocks per attention layer (:func:`repro.nn.layers.init_kv_pool`; the
 +1 is the trash block dead writes scatter into).  This module is the HOST
-side: :class:`PagedConfig` (the knob bundle `ServeEngine`/`LMEngine` thread
-down, the way ``FusedConfig`` threads the resonator path) and
-:class:`BlockTablePool` (the allocator — per-slot block lists over one free
-list, and the trash-padded ``[slots, W]`` table the kernels index through).
+side: :class:`PagedConfig` (the knob bundle ``LMEngine`` threads down to
+:class:`repro.lm.kv.PagedKV`, the way ``FusedConfig`` threads the resonator
+path) and :class:`BlockTablePool` (the allocator — per-slot block lists
+over one free list, and the trash-padded ``[slots, W]`` table the kernels
+index through).
 
 What paging buys the serving stack:
 
@@ -45,16 +46,14 @@ class PagedConfig:
     keeping per-slot capacity aligned with the contiguous engine's
     ``max_len`` contract; raise it — and ``num_blocks`` — to serve slots
     past ``max_len``).  ``prefill_chunk`` is the static prompt-chunk width
-    (one dispatch per chunk).  ``use_flash`` selects the Pallas
-    online-softmax kernel vs the dense gathered reference; ``interpret``
-    follows the ``FusedConfig`` convention (``None`` = interpret off-TPU).
+    (one dispatch per chunk).  ``interpret`` follows the ``FusedConfig``
+    convention (``None`` = interpret off-TPU).
     """
 
     block_size: int = 16
     num_blocks: int | None = None
     max_blocks_per_slot: int | None = None
     prefill_chunk: int = 8
-    use_flash: bool = True
     interpret: bool | None = None
 
     def __post_init__(self):

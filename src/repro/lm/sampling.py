@@ -1,19 +1,18 @@
 """Per-request sampling specs for LM serving.
 
-:class:`SamplingSpec` is what ``LMEngine.submit(..., sampling=...)`` and
-``ServeEngine.add_request`` accept: temperature / top-k with a per-request
-seed.  The PRNG key for each emitted token is ``fold_in(PRNGKey(seed),
-absolute_position)`` — a pure function of the request's own seed and the
-token's position, NOT of wall-clock or engine state — so a replayed
-request (fault recovery, resize re-queue) regenerates bit-equal tokens,
-the same warm-handoff contract greedy decode gets for free.
+:class:`SamplingSpec` is what ``LMEngine.submit(..., sampling=...)``
+accepts: temperature / top-k with a per-request seed.  The PRNG key for
+each emitted token is ``fold_in(PRNGKey(seed), absolute_position)`` — a
+pure function of the request's own seed and the token's position, NOT of
+wall-clock or engine state — so a replayed request (fault recovery, resize
+re-queue) regenerates bit-equal tokens, the same warm-handoff contract
+greedy decode gets for free.
 
-Validation lives in ``__post_init__`` so the two historical footguns die
-with a clear message at construction instead of an opaque jax error at
-decode time: ``temperature=0`` (a divide-by-zero inside ``categorical`` —
-zero temperature IS greedy, ask for that) and a missing key (the engine
-API derives keys from ``seed``; the raw ``ServeEngine.step(sampler=...)``
-path validates its explicit ``key=`` separately).
+Validation lives in ``__post_init__`` so ``temperature=0`` (a
+divide-by-zero inside ``categorical`` — zero temperature IS greedy, ask for
+that) dies with a clear message at construction instead of an opaque jax
+error at decode time; there is no key to forget, keys derive from
+``seed``.
 """
 from __future__ import annotations
 

@@ -389,7 +389,7 @@ def contiguous_unsupported_reason(cfg: ModelConfig) -> str | None:
     """None when the contiguous cache can serve ``cfg``, else the reason."""
     if cfg.mla is not None:
         return ("latent attention (MLA) is served from the paged latent "
-                "pool only (ServeEngine(paged=PagedConfig(...))); the "
+                "pool only (LMEngine(paged=PagedConfig(...))); the "
                 "contiguous cache holds per-head K/V")
     return None
 

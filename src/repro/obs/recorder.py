@@ -2,7 +2,7 @@
 you don't.
 
 Every serving layer takes an ``obs=`` recorder (``Engine``, ``LMEngine``,
-``ServeEngine``, ``Runtime``) defaulting to the :data:`NULL` singleton,
+``Runtime``) defaulting to the :data:`NULL` singleton,
 whose every method is a constant-time no-op returning shared singletons —
 no per-step allocation, no device work, no captured state inside jitted
 code (recording always happens AROUND dispatches).  The disabled path is

@@ -1,22 +1,19 @@
 """LMEngine: transformer serving behind the engine-style Steppable API.
 
-Retires the ROADMAP item "serve the LM ``ServeEngine`` (launch/serve.py)
-through the engine API".  ``launch/serve.ServeEngine`` stays the device
-layer (masked batch decode over a shared KV cache, per-slot prefill); this
-adapter adds the request layer the factorizer ``Engine`` already has —
-queueing, slot ownership, burst-scan retirement, per-request latency
-accounting — so one :class:`repro.runtime.Runtime` can interleave LM decode
-with symbolic factorization engines.
-
-With ``paged=PagedConfig(...)`` (or ``REPRO_LM_PAGED=1`` in the
-environment) the device layer serves from the block-table KV pool
-(:mod:`repro.lm.paging`): chunked prefill, flash-decode attention, and —
-the piece the contiguous layout could never offer — :meth:`resize` as a
+One class holds every LM request and slot: the queue, slot ownership, each
+slot's KV length, active flag and next input token, retirement, resize,
+recovery, preemption, counters and spans, so one
+:class:`repro.runtime.Runtime` can interleave LM decode with symbolic
+factorization engines.  The device side is one of the two KV layouts of
+:mod:`repro.lm.kv`, chosen once at construction: the block-table pool
+(:class:`~repro.lm.kv.PagedKV`) when ``paged`` is a :class:`PagedConfig`
+or the stack has latent attention, else the contiguous cache
+(:class:`~repro.lm.kv.ContiguousKV`).  On the pool :meth:`resize` is a
 block-table edit, so the Runtime's EWMA re-tuner warm-hands-off the LM
 engine exactly like the factorizer engines (in-flight slots carried
-bit-equal).  On the contiguous layout :meth:`resize` still exists but
-replays: live requests re-queue from their pinned prompts (deterministic
-decode makes the replayed tokens bit-equal, the ``recover()`` argument).
+bit-equal).  On the contiguous cache :meth:`resize` replays: live requests
+re-queue from their pinned prompts (deterministic decode makes the replayed
+tokens bit-equal, the ``recover()`` argument).
 
 The adSCH connection runs through the registered ``lm_decode`` spec
 (:mod:`repro.engine.pipelines`): its StageGraph declares prefill as the
@@ -31,21 +28,22 @@ Retirement is at burst granularity (like the factorizer engine's sweep
 bursts): a slot may overshoot its stop condition by up to
 ``decode_per_step - 1`` tokens; the finished request's ``tokens`` are
 trimmed to ``max_new_tokens`` / first EOS (``logits`` holds each kept
-token's f32 logit), and a slot parked by the device layer's KV-capacity
-guard retires with ``truncated=True``.  On the paged pool a request is
-admitted only when the blocks it can reach (prompt, ``max_new_tokens`` and
-one burst's overshoot) are free beyond those the live slots may still
-claim, so a pool that admits never parks a slot mid-generation.
-``sweeps_total`` counts decode steps over the slot batch, the LM's
-counterpart of a resonator sweep.
+token's f32 logit), and a slot parked at KV capacity retires with
+``truncated=True``.  On the paged pool a request is admitted only when the
+blocks it can reach (prompt, ``max_new_tokens`` and one burst's overshoot)
+are free beyond those the live slots may still claim, so a pool that admits
+never parks a slot mid-generation.  ``sweeps_total`` counts decode steps
+over the slot batch, the LM's counterpart of a resonator sweep.
 """
 from __future__ import annotations
 
 import dataclasses
-import os
+import functools
 from collections import deque
 from typing import Any
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro import obs as obs_mod
@@ -54,8 +52,9 @@ from repro.core import scheduler as sch
 from repro.engine import registry
 from repro.engine.engine import (LAT_WINDOW_CAP, derive_sweeps_per_step,
                                  rolling_latency_ms, step_unit_ops)
-from repro.launch.serve import ServeEngine
-from repro.lm.paging import PagedConfig, cdiv
+from repro.lm import sampling as lm_sampling
+from repro.lm.kv import ContiguousKV, PagedKV
+from repro.lm.paging import PagedConfig
 from repro.lm.sampling import SamplingSpec
 from repro.nn import transformer as T
 
@@ -83,22 +82,15 @@ class LMRequest:
             self.done_time - self.submit_time
 
 
-def _resolve_paged(paged, cfg) -> PagedConfig | None:
-    if paged is None:
-        # stacks the contiguous cache cannot hold (latent attention) serve
-        # paged whatever the environment says
-        paged_only = T.contiguous_unsupported_reason(cfg) is not None
-        return PagedConfig() if paged_only or os.environ.get(
-            "REPRO_LM_PAGED") else None
-    if paged is True:
-        return PagedConfig()
-    if paged is False:
-        return None
-    return paged  # ServeEngine type-checks the PagedConfig
+def _carry(a: np.ndarray, rows: list, n: int) -> np.ndarray:
+    """``a``'s entries at ``rows`` in rows 0.., zero-padded to ``n``."""
+    out = np.zeros(n, a.dtype)
+    out[:len(rows)] = a[rows]
+    return out
 
 
 class LMEngine:
-    """``submit()/step()/drain()`` continuous batching over ``ServeEngine``.
+    """``submit()/step()/drain()`` continuous batching over a KV layout.
 
     Satisfies :class:`repro.runtime.protocol.Steppable`; requests are token
     prompts instead of query vectors, results are generated token lists.
@@ -108,12 +100,18 @@ class LMEngine:
 
     def __init__(self, cfg, params, *, slots: int = 4, max_len: int = 128,
                  prompt_len_hint: int = 16, decode_per_step: int | None = None,
-                 eos_id: int | None = None, paged=None, hw=hw_model.COGSYS,
-                 obs=None, clock=None):
+                 eos_id: int | None = None, paged: PagedConfig | None = None,
+                 hw=hw_model.COGSYS, obs=None, clock=None):
+        if paged is not None and not isinstance(paged, PagedConfig):
+            raise TypeError(
+                f"paged= expects a PagedConfig or None, got {paged!r}")
+        if paged is None and T.contiguous_unsupported_reason(cfg) is not None:
+            paged = PagedConfig()  # latent stacks serve paged only
+        self._layout = functools.partial(ContiguousKV, cfg) if paged is None \
+            else functools.partial(PagedKV, cfg, paged=paged)
         self.cfg, self.hw = cfg, hw
         self.slots = slots
         self.eos_id = eos_id
-        self.paged = _resolve_paged(paged, cfg)
         self._prompt_len_hint = prompt_len_hint
         self._dps_pinned = decode_per_step is not None
         # Observability seam, mirroring Engine: spans/counters around the
@@ -125,12 +123,12 @@ class LMEngine:
         # kept for fault recovery: recover() rebuilds the device layer from
         # these (params are read-only serving state, never mutated by decode)
         self._params, self._max_len = params, max_len
-        self.serve = self._make_serve(slots)
+        self.serve = self._layout(params, slots, max_len)
         self.spec = self._build_spec(slots)
         self.decode_per_step = (
             derive_sweeps_per_step(self.spec, slots, hw)
             if decode_per_step is None else decode_per_step)
-        self._owner: list = [None] * slots  # LMRequest | None
+        self._clear_slots()
         self._queue: deque = deque()
         self._next_id = 0
         self.completed: dict = {}
@@ -140,15 +138,35 @@ class LMEngine:
         self.tokens_total = 0
         self.recoveries_total = 0
         self.resizes_total = 0
+        # structural serving metrics (interpret-mode wall time is not the
+        # signal; these are): dispatches and modeled KV bytes per decode
+        self.prefill_dispatches = 0
+        self.decode_dispatches = 0
+        self.kv_bytes_touched = 0
+        # token-expert picks of live decode rows on the experts held here
+        # (expert-share MoE stacks; pulled with each step's tokens), and
+        # the (layer, held expert) pairs they fall on
+        self.held_picks_total = 0
+        moe = cfg.moe if cfg.moe is not None and cfg.moe.held else None
+        self.held_experts = 0 if moe is None else moe.held * cfg.n_periods \
+            * sum(k == "attn_moe" for k in cfg.block_pattern)
+        # [slots, 1, vocab] fp32 logits of the latest decode step (None
+        # before the first): what layouts and kernels are compared on
+        self.last_logits = None
         self._lat_sum = 0.0
         self._lat_window: list = []
         self._step_cost = self._modeled_step_cost()
         self._record_structure()
 
-    def _make_serve(self, slots: int, paged="inherit") -> ServeEngine:
-        return ServeEngine(self.cfg, self._params, slots, self._max_len,
-                           paged=self.paged if paged == "inherit" else paged,
-                           obs=self.obs, obs_track=self.obs_track)
+    def _clear_slots(self) -> None:
+        """Every slot free, with the host mirror of its KV: ``lens`` (the
+        positions written; a decode step writes at ``len``), ``active``
+        (decoding: owned and not parked at KV capacity) and the token the
+        next decode step feeds."""
+        self._owner: list = [None] * self.slots  # LMRequest | None
+        self.active = np.zeros(self.slots, bool)
+        self.lens = np.zeros(self.slots, np.int64)
+        self._last = np.zeros(self.slots, np.int32)
 
     def _record_structure(self) -> None:
         if not self.obs.enabled:
@@ -156,26 +174,22 @@ class LMEngine:
         track = self.obs_track
         self.obs.gauge("slots", self.slots, engine=track)
         self.obs.gauge("units_per_step", self.decode_per_step, engine=track)
-        self.obs.gauge("paged", int(self.paged is not None), engine=track)
+        self.obs.gauge("paged", int(self.serve.paged), engine=track)
 
     def bind_obs(self, obs, track: str | None = None) -> None:
-        """Adopt a recorder after construction (see ``Engine.bind_obs``);
-        also rebinds the device layer so prefill-chunk spans and dispatch
-        counters land in the same registry."""
+        """Adopt a recorder after construction (see ``Engine.bind_obs``)."""
         self.obs = obs
         if track is not None:
             self.obs_track = track
         if self._default_clock:
             self._clock = obs.clock
-        self.serve.obs = obs
-        self.serve.obs_track = self.obs_track
         self._record_structure()
 
     def _build_spec(self, slots: int):
         return registry.build(
             "lm_decode", None, cfg=self.cfg, batch=slots,
             prompt_len=self._prompt_len_hint, max_len=self._max_len,
-            kv_block=None if self.paged is None else self.paged.block_size)
+            kv_block=self.serve.kv_block)
 
     def _modeled_step_cost(self) -> float:
         ops = step_unit_ops(self.spec, self.slots)
@@ -199,10 +213,12 @@ class LMEngine:
         prompt = np.asarray(prompt, np.int32)
         if prompt.ndim != 1 or prompt.shape[0] == 0:
             raise ValueError("submit expects a non-empty 1-D token prompt")
-        if prompt.shape[0] > self.serve.slot_capacity:
+        # prompt[:-1] prefills and the last token still needs a KV position
+        # on the first decode step: len(prompt) positions in all
+        if prompt.shape[0] > self.serve.capacity:
             raise ValueError(
                 f"prompt of {prompt.shape[0]} tokens exceeds the engine's "
-                f"KV capacity {self.serve.slot_capacity}")
+                f"KV capacity {self.serve.capacity}")
         if sampling is not None and not isinstance(sampling, SamplingSpec):
             raise TypeError(
                 f"sampling= expects a SamplingSpec or None, got {sampling!r}")
@@ -226,41 +242,82 @@ class LMEngine:
                 best_i, best = i, k
         return best_i
 
+    def _reach(self, req: LMRequest) -> int:
+        """Positions a request can grow to: its prompt, every token it may
+        generate and one burst's overshoot."""
+        return len(req.prompt) + req.max_new_tokens + self.decode_per_step
+
     def _fill(self) -> None:
+        serve = self.serve
         for slot in range(self.slots):
             if self._owner[slot] is not None or not self._queue:
                 continue
             i = self._next_index()
             req = self._queue[i]
+            n = len(req.prompt)
             # paged: a drained pool defers admission (priority order
             # preserved — the BEST candidate parks) until retiring slots
             # release blocks — parking, not rejection
-            if not self.serve.can_admit(int(req.prompt.shape[0]),
-                                        self._claims(req)):
+            live = ((s, self._reach(r)) for s, r in enumerate(self._owner)
+                    if r is not None)
+            if not serve.can_admit(n, serve.claims(live, n,
+                                                   self._reach(req))):
                 break
             del self._queue[i]
             self._owner[slot] = req
-            self.serve.add_request(slot, req.prompt, sampling=req.sampling)
+            req.tokens, req.logits = [], []  # a replay starts afresh
+            _, dispatches = serve.prefill(slot, req.prompt, self.obs,
+                                          self.obs_track)
+            self.prefill_dispatches += dispatches
+            if self.obs.enabled and dispatches:
+                self.obs.count("prefill_dispatches", dispatches,
+                               engine=self.obs_track)
+            self.active[slot] = True
+            self.lens[slot] = n - 1
+            self._last[slot] = req.prompt[-1]
 
-    def _reach_blocks(self, req: LMRequest) -> int:
-        """Pool blocks a request can grow to: its prompt, every token it may
-        generate and one burst's overshoot (at most the whole pool)."""
+    def _decode(self) -> bool:
+        """One masked decode step over the slot batch.  Slots with no KV
+        room for this step's write park first, in ascending slot order
+        (deterministic under replay); a parked slot keeps its KV until it
+        retires.  Returns False when nothing is left to decode."""
         serve = self.serve
-        tokens = min(int(req.prompt.shape[0]) + req.max_new_tokens
-                     + self.decode_per_step, serve.slot_capacity)
-        return min(cdiv(tokens, self.paged.block_size),
-                   serve.blocks.num_blocks)
-
-    def _claims(self, req: LMRequest) -> int:
-        """Blocks the live slots may still take, plus what admitting ``req``
-        needs beyond its prompt: admission leaves them free."""
-        if self.paged is None:
-            return 0
-        rows = self.serve.blocks.rows
-        live = sum(max(0, self._reach_blocks(r) - len(rows[s]))
-                   for s, r in enumerate(self._owner) if r is not None)
-        prompt = cdiv(int(req.prompt.shape[0]), self.paged.block_size)
-        return live + max(0, self._reach_blocks(req) - prompt)
+        for s in np.flatnonzero(self.active):
+            if not serve.grow(s, int(self.lens[s]) + 1):
+                self.active[s] = False
+        if not self.active.any():
+            return False
+        logits, top, top_logit, held = serve.decode(
+            self._last[:, None], self.lens, self.active)
+        self.last_logits = logits
+        self.decode_dispatches += 1
+        kv_bytes = serve.kv_step_bytes(self.lens)
+        self.kv_bytes_touched += kv_bytes
+        if self.obs.enabled:
+            self.obs.count("decode_dispatches", 1, engine=self.obs_track)
+            self.obs.count("kv_bytes_touched", kv_bytes,
+                           engine=self.obs_track)
+        self.lens[self.active] += 1
+        nxt, nxt_logit, held = jax.device_get((top, top_logit, held))
+        nxt, nxt_logit = np.array(nxt), np.array(nxt_logit)
+        self.held_picks_total += int(held)
+        live = np.flatnonzero(self.active)
+        sampled = False
+        for s in live:
+            spec = self._owner[s].sampling
+            if spec is not None:
+                nxt[s] = lm_sampling.sample_token(logits[s, -1], spec,
+                                                  int(self.lens[s]))
+                sampled = True
+        if sampled:  # the chosen tokens' logits, in one gather and pull
+            nxt_logit = np.array(jnp.take_along_axis(
+                logits[:, -1], jnp.asarray(nxt, jnp.int32)[:, None], -1)[:, 0])
+        for s in live:
+            req = self._owner[s]
+            req.tokens.append(int(nxt[s]))
+            req.logits.append(float(nxt_logit[s]))
+        self._last[live] = nxt[live]
+        return True
 
     def _stop_at(self, req: LMRequest, produced: list) -> int | None:
         """Index (exclusive) to trim `produced` at, or None if not done."""
@@ -270,20 +327,23 @@ class LMEngine:
             return req.max_new_tokens
         return None
 
+    def _release(self, slot: int) -> None:
+        """Free a slot (paged: its blocks go back to the pool)."""
+        self._owner[slot] = None
+        self.active[slot] = False
+        self.serve.release(slot)
+
     def _retire(self) -> list:
         finished = []
-        for slot in range(self.slots):
-            req = self._owner[slot]
+        for slot, req in enumerate(self._owner):
             if req is None:
                 continue
-            # generated[0] is the seeded last prompt token, not an output
-            produced = self.serve.generated[slot][1:]
-            stop = self._stop_at(req, produced)
-            if stop is None and not self.serve.overflowed[slot]:
+            stop = self._stop_at(req, req.tokens)
+            if stop is None and self.active[slot]:
                 continue
             req.truncated = stop is None  # parked at KV capacity
-            req.tokens = produced[:stop] if stop is not None else produced
-            req.logits = self.serve.generated_logits[slot][:len(req.tokens)]
+            req.tokens = req.tokens[:stop]
+            req.logits = req.logits[:len(req.tokens)]
             req.done_time = self._clock()
             req.result = {"tokens": req.tokens, "logits": req.logits,
                           "truncated": req.truncated}
@@ -293,8 +353,7 @@ class LMEngine:
             self._lat_sum += req.latency_s
             self._lat_window.append(req.latency_s)
             del self._lat_window[:-LAT_WINDOW_CAP]
-            self._owner[slot] = None
-            self.serve.release_slot(slot)  # paged: blocks back to the pool
+            self._release(slot)
             finished.append(req)
         return finished
 
@@ -308,18 +367,17 @@ class LMEngine:
             live = sum(o is not None for o in self._owner)
             if not live:
                 return []
-            serve = self.serve
-            held0 = serve.held_picks_total
+            held0 = self.held_picks_total
             with obs.span("decode-burst", track=self.obs_track,
                           cat="engine") as bp:
                 n = kv_tokens = 0
                 for _ in range(self.decode_per_step):
                     # every live slot parked at capacity ends the burst early
-                    if serve.step() is None:
+                    if not self._decode():
                         break
                     n += 1
                     if obs.enabled:  # cached positions this step read
-                        kv_tokens += int(serve.lens[serve.active].sum())
+                        kv_tokens += int(self.lens[self.active].sum())
             self.steps_total += 1
             self.sweeps_total += n
             with obs.span("retire", track=self.obs_track, cat="engine"):
@@ -327,9 +385,9 @@ class LMEngine:
         if obs.enabled:
             bp.args.update(live=live, slots=self.slots, steps=n,
                            kv_tokens=kv_tokens)
-            if serve.held_experts:
-                bp.args.update(held_picks=serve.held_picks_total - held0,
-                               held_experts=serve.held_experts)
+            if self.held_experts:
+                bp.args.update(held_picks=self.held_picks_total - held0,
+                               held_experts=self.held_experts)
             sp.args.update(decodes=n, retired=len(finished))
             obs.count("steps", 1, engine=self.obs_track)
             obs.count("decode_steps", n, engine=self.obs_track)
@@ -371,21 +429,18 @@ class LMEngine:
             return
         rsid = self.obs.begin("resize", track=self.obs_track, cat="engine",
                               args={"from": self.slots, "to": new_slots})
-        live = [(s, self._owner[s]) for s in range(self.slots)
-                if self._owner[s] is not None]
-        if self.paged is not None:
-            keep, overflow = live[:new_slots], live[new_slots:]
-            for _, req in reversed(overflow):
-                self._queue.appendleft(req)
-            self.serve.resize(new_slots, [s for s, _ in keep])
-            self._owner = [req for _, req in keep] + \
-                [None] * (new_slots - len(keep))
-        else:
-            keep, overflow = [], live
-            for _, req in reversed(live):
-                self._queue.appendleft(req)
-            self.serve = self._make_serve(new_slots, paged=None)
-            self._owner = [None] * new_slots
+        live = [(s, r) for s, r in enumerate(self._owner) if r is not None]
+        keep = live[:new_slots] if self.serve.paged else []
+        overflow = live[len(keep):]
+        for _, req in reversed(overflow):
+            self._queue.appendleft(req)
+        rows = [s for s, _ in keep]
+        self.serve.resize(new_slots, rows)
+        self._owner = [req for _, req in keep] + \
+            [None] * (new_slots - len(keep))
+        self.active, self.lens, self._last = (
+            _carry(a, rows, new_slots)
+            for a in (self.active, self.lens, self._last))
         self.slots = new_slots
         self.spec = self._build_spec(new_slots)
         if not self._dps_pinned:
@@ -404,22 +459,22 @@ class LMEngine:
         """Rebuild the device layer after a fault and replay in-flight
         generations; returns the number of replayed requests.
 
-        A fresh :class:`ServeEngine` replaces the (possibly corrupt) KV
-        state and slot bookkeeping; live requests re-queue at the FRONT in
+        A fresh KV layout replaces the (possibly corrupt) cache or pool and
+        every slot is freed; live requests re-queue at the FRONT in
         submission order and re-run prefill + decode from their pinned
         prompts.  Greedy decode is deterministic and sampled requests
         re-derive their keys from (seed, position), so a replayed request's
         tokens are bit-equal to a fault-free run — partially generated
-        tokens are simply regenerated (``_retire`` reads the device layer's
-        ``generated``, which the rebuild reset).
+        tokens are simply regenerated.
         """
         with self.obs.span("recover", track=self.obs_track,
                            cat="engine") as sp:
             live = [req for req in self._owner if req is not None]
             for req in reversed(live):
                 self._queue.appendleft(req)
-            self.serve = self._make_serve(self.slots)
-            self._owner = [None] * self.slots
+            self.serve = self._layout(self._params, self.slots,
+                                      self._max_len)
+            self._clear_slots()
             self.recoveries_total += 1
             if sp is not None:
                 # "recoveries" as a metric is supervision-scoped (counted by
@@ -439,8 +494,7 @@ class LMEngine:
         """
         for slot, req in enumerate(self._owner):
             if req is not None and req.id == request_id:
-                self._owner[slot] = None
-                self.serve.release_slot(slot)
+                self._release(slot)
                 self._queue.appendleft(req)
                 self.obs.instant("preempt", track=self.obs_track,
                                  cat="engine",
@@ -461,8 +515,7 @@ class LMEngine:
                 return True
         for slot, req in enumerate(self._owner):
             if req is not None and req.id == request_id:
-                self._owner[slot] = None
-                self.serve.release_slot(slot)
+                self._release(slot)
                 return True
         return False
 
@@ -501,15 +554,15 @@ class LMEngine:
             "units_per_step": self.decode_per_step,
             "units_total": self.tokens_total,
             "decode_per_step": self.decode_per_step,
-            "paged": self.paged is not None,
+            "paged": self.serve.paged,
             "steps": self.steps_total,
             "completed": self.completed_total,
             "tokens_total": self.tokens_total,
             "recoveries": self.recoveries_total,
             "resizes": self.resizes_total,
-            "prefill_dispatches": self.serve.prefill_dispatches,
-            "decode_dispatches": self.serve.decode_dispatches,
-            "kv_bytes_touched": self.serve.kv_bytes_touched,
+            "prefill_dispatches": self.prefill_dispatches,
+            "decode_dispatches": self.decode_dispatches,
+            "kv_bytes_touched": self.kv_bytes_touched,
             "window_completed": len(lats),
             **rolling_latency_ms(lats),
             "latency_mean_all_ms": (self._lat_sum / self.completed_total * 1e3

@@ -13,7 +13,7 @@ import pytest
 from repro.configs import deepseek_v2_lite as D
 from repro.kernels.flash_decode import kernel as fdk
 from repro.kernels.flash_decode import ref as fdr
-from repro.launch.serve import ServeEngine
+from repro.lm.kv import ContiguousKV
 from repro.lm.paging import PagedConfig
 from repro.models import deepseek_v2_ref as R
 from repro.nn import layers as L
@@ -205,15 +205,14 @@ def test_yarn_frequencies_and_scale():
 
 def test_contiguous_path_refuses_mla():
     """(g) The contiguous cache holds per-head K/V: it refuses MLA with the
-    reason instead of mis-serving it."""
+    reason instead of mis-serving it, and LMEngine serves MLA paged."""
     cfg = D.smoke()
     params, _ = T.init(jax.random.PRNGKey(0), cfg)
     with pytest.raises(ValueError, match="latent"):
         T.init_cache(cfg, 2, 16)
     with pytest.raises(ValueError, match="paged"):
-        ServeEngine(cfg, params, 2, 16)
-    with pytest.raises(ValueError, match="latent"):
-        LMEngine(cfg, params, slots=2, max_len=16, paged=False)
+        ContiguousKV(cfg, params, 2, 16)
+    assert LMEngine(cfg, params, slots=2, max_len=16).snapshot()["paged"]
 
 
 def test_full_config_counts():

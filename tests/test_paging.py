@@ -11,8 +11,8 @@ import pytest
 import repro.runtime as rt
 from repro.configs.registry import ARCHS
 from repro.launch.programs import primitive_names
-from repro.launch.serve import ServeEngine
 from repro.lm import model as lm_model
+from repro.lm.kv import ContiguousKV, PagedKV
 from repro.lm.paging import BlockTablePool, PagedConfig
 from repro.lm.sampling import SamplingSpec
 from repro.nn import transformer as T
@@ -26,7 +26,8 @@ def smoke():
 
 
 def _prompt(seed, n, cfg):
-    return jax.random.randint(jax.random.PRNGKey(seed), (n,), 0, cfg.vocab)
+    return np.asarray(jax.random.randint(jax.random.PRNGKey(seed), (n,), 0,
+                                         cfg.vocab), np.int32)
 
 
 # -- PagedConfig / BlockTablePool unit ---------------------------------------
@@ -39,7 +40,7 @@ def test_paged_config_validation():
     with pytest.raises(ValueError, match="num_blocks"):
         PagedConfig(num_blocks=0)
     with pytest.raises(TypeError, match="PagedConfig"):
-        ServeEngine(None, None, 1, 8, paged=True)
+        rt.LMEngine(None, None, slots=1, max_len=8, paged=True)
 
 
 def test_pool_alloc_release_and_table():
@@ -79,50 +80,56 @@ def test_paged_stream_equals_contiguous_greedy(smoke, kv_dtype):
     if kv_dtype == "int8":
         cfg = dataclasses.replace(cfg, kv_cache_dtype="int8")
         params, _ = T.init(jax.random.PRNGKey(0), cfg)
-    ref = ServeEngine(cfg, params, 3, 32)
-    eng = ServeEngine(cfg, params, 3, 32,
-                      paged=PagedConfig(block_size=8, prefill_chunk=4))
+    paged = PagedConfig(block_size=8, prefill_chunk=4)
+    ref = ContiguousKV(cfg, params, 3, 32)
+    kv = PagedKV(cfg, params, 3, 32, paged)
     # mixed lengths: 1-token (nothing to prefill), off/at chunk boundary
-    for s, n in enumerate((1, 5, 9)):
-        p = _prompt(s + 1, n, cfg)
-        lr = ref.add_request(s, p)
-        lp = eng.add_request(s, p)
+    prompts = [_prompt(s + 1, n, cfg) for s, n in enumerate((1, 5, 9))]
+    for s, p in enumerate(prompts):
+        lr, _ = ref.prefill(s, p)
+        lp, _ = kv.prefill(s, p)
         if lr is None:
             assert lp is None
         else:  # chunked prefill emits the SAME last-token logits, bit-equal
             np.testing.assert_array_equal(np.asarray(lr), np.asarray(lp))
-    for _ in range(6):
-        ref.step()
-        eng.step()
-    for s in range(3):
-        assert eng.generated[s] == ref.generated[s], s
+    streams = []
+    for layout in (None, paged):
+        eng = rt.LMEngine(cfg, params, slots=3, max_len=32,
+                          decode_per_step=2, paged=layout)
+        ids = [eng.submit(p, max_new_tokens=6) for p in prompts]
+        done = {r.id: r.tokens for r in eng.drain()}
+        streams.append([done[i] for i in ids])
+    assert streams[0] == streams[1]
+    assert all(len(t) == 6 for t in streams[0])
 
 
 def test_greedy_stream_bitstable_across_block_sizes(smoke):
     cfg, params = smoke
     streams = []
     for bs, chunk in ((4, 3), (8, 4), (16, 8)):
-        eng = ServeEngine(cfg, params, 2, 32,
+        eng = rt.LMEngine(cfg, params, slots=2, max_len=32,
+                          decode_per_step=2,
                           paged=PagedConfig(block_size=bs,
                                             prefill_chunk=chunk))
-        eng.add_request(0, _prompt(2, 6, cfg))
-        eng.add_request(1, _prompt(3, 9, cfg))
-        for _ in range(6):
-            eng.step()
-        streams.append([list(eng.generated[s]) for s in range(2)])
+        eng.submit(_prompt(2, 6, cfg), max_new_tokens=6)
+        eng.submit(_prompt(3, 9, cfg), max_new_tokens=6)
+        streams.append([r.tokens for r in eng.drain()])
     assert streams[0] == streams[1] == streams[2]
 
 
 def test_chunked_prefill_dispatch_count(smoke):
     cfg, params = smoke
-    eng = ServeEngine(cfg, params, 2, 32,
+    eng = rt.LMEngine(cfg, params, slots=2, max_len=32,
                       paged=PagedConfig(block_size=8, prefill_chunk=4))
-    eng.add_request(0, _prompt(4, 10, cfg))  # 9 prefill tokens -> 3 chunks
+    eng.submit(_prompt(4, 10, cfg))  # 9 prefill tokens -> 3 chunks
+    eng._fill()
     assert eng.prefill_dispatches == 3
-    eng.add_request(1, _prompt(5, 5, cfg))   # 4 prefill tokens -> 1 chunk
+    eng.submit(_prompt(5, 5, cfg))   # 4 prefill tokens -> 1 chunk
+    eng._fill()
     assert eng.prefill_dispatches == 4
-    ref = ServeEngine(cfg, params, 2, 32)
-    ref.add_request(0, _prompt(4, 10, cfg))
+    ref = rt.LMEngine(cfg, params, slots=2, max_len=32)
+    ref.submit(_prompt(4, 10, cfg))
+    ref._fill()
     assert ref.prefill_dispatches == 9  # contiguous: one per token
 
 
@@ -130,7 +137,7 @@ def test_one_pallas_call_per_decode_step(smoke):
     """The flash path runs EXACTLY one pallas_call per decode dispatch —
     the kernel sits inside the scan-over-periods body."""
     cfg, params = smoke
-    eng = ServeEngine(cfg, params, 2, 32, paged=PagedConfig(block_size=8))
+    eng = PagedKV(cfg, params, 2, 32, PagedConfig(block_size=8))
 
     jaxpr = jax.make_jaxpr(
         lambda p, pool, table, lens, tok, act: lm_model.decode_step_paged(
@@ -147,37 +154,39 @@ def test_one_pallas_call_per_decode_step(smoke):
 
 def test_pool_exhaustion_parks_and_recovers(smoke):
     cfg, params = smoke
-    eng = ServeEngine(cfg, params, 2, 32,
-                      paged=PagedConfig(block_size=4, num_blocks=3,
-                                        max_blocks_per_slot=3))
-    eng.add_request(0, _prompt(6, 4, cfg))  # 1 block
-    eng.add_request(1, _prompt(7, 5, cfg))  # 2 blocks -> pool drained
-    assert eng.blocks.free_blocks == 0
-    # slot 0 parks when it needs a 2nd block (len 4 -> 5); slot 1 runs on
-    for _ in range(3):
-        eng.step()
-    assert not eng.active[0] and eng.overflowed[0]
-    assert eng.active[1] and not eng.overflowed[1]
+    kv = PagedKV(cfg, params, 2, 32,
+                 PagedConfig(block_size=4, num_blocks=3,
+                             max_blocks_per_slot=3))
+    kv.prefill(0, _prompt(6, 4, cfg))  # 1 block
+    kv.prefill(1, _prompt(7, 5, cfg))  # 2 blocks -> pool drained
+    assert kv.blocks.free_blocks == 0
+    # slot 0 cannot take a 2nd block (len 4 -> 5); slot 1 runs on in its 2
+    assert not kv.grow(0, 5)
+    assert kv.grow(1, 8) and not kv.grow(1, 9)
+    lens, act = np.asarray([4, 4]), np.asarray([False, True])
+    logits, *_ = kv.decode(np.zeros((2, 1), np.int32), lens, act)
+    assert bool(np.isfinite(np.asarray(logits[1])).all())
     # releasing the parked slot lets slot 1 grow into the freed block
-    eng.release_slot(0)
-    assert eng.blocks.free_blocks == 1
-    for _ in range(4):  # len 7 -> 8 crosses into a 3rd block
-        assert eng.step() is not None
-    assert eng.active[1] and eng.lens[1] == 11
+    kv.release(0)
+    assert kv.blocks.free_blocks == 1
+    assert kv.grow(1, 11)  # len 8 -> 9 crosses into a 3rd block
+    assert kv.blocks.free_blocks == 0 and kv.blocks.capacity(1) == 12
 
 
 def test_slot_capacity_exceeds_max_len_when_pool_allows(smoke):
     cfg, params = smoke
-    eng = ServeEngine(cfg, params, 1, 8,
-                      paged=PagedConfig(block_size=8, num_blocks=4,
-                                        max_blocks_per_slot=4))
-    assert eng.slot_capacity == 32  # pool-limited, not max_len=8
-    eng.add_request(0, _prompt(8, 12, cfg))  # > max_len admits fine
+    paged = PagedConfig(block_size=8, num_blocks=4, max_blocks_per_slot=4)
+    assert PagedKV(cfg, params, 1, 8, paged).capacity == 32  # not max_len=8
+    eng = rt.LMEngine(cfg, params, slots=1, max_len=8, decode_per_step=1,
+                      paged=paged)
+    rid = eng.submit(_prompt(8, 12, cfg), max_new_tokens=8)  # > max_len
     for _ in range(4):
-        assert eng.step() is not None
-    assert eng.lens[0] == 15 and not eng.overflowed[0]
-    with pytest.raises(ValueError, match="exceeds the cache capacity"):
-        eng.add_request(0, _prompt(8, 33, cfg))
+        assert eng.step() == []
+    assert eng.lens[0] == 15 and eng.active[0]
+    done = eng.drain()
+    assert [r.id for r in done] == [rid] and not done[0].truncated
+    with pytest.raises(ValueError, match="exceeds the engine's KV capacity"):
+        eng.submit(_prompt(8, 33, cfg))
 
 
 def test_lm_engine_defers_admission_until_pool_frees(smoke):
@@ -247,6 +256,8 @@ def test_contiguous_resize_replays_bit_equal(smoke):
     got = {r.id: r.tokens for r in eng.drain()}
     want = {r.id: r.tokens for r in ref.drain()}
     assert got == want
+    # the counters outlive the rebuilt cache: the replays prefill again
+    assert eng.prefill_dispatches > ref.prefill_dispatches
 
 
 def test_resize_preserves_sampled_requests(smoke):
@@ -280,18 +291,12 @@ def test_sampling_spec_validation():
 
 def test_step_sampler_footguns_die_loudly(smoke):
     cfg, params = smoke
-    eng = ServeEngine(cfg, params, 1, 16)
-    eng.add_request(0, _prompt(40, 4, cfg))
-    with pytest.raises(ValueError, match="PRNG key"):
-        eng.step(sampler="categorical")  # key=None used to die inside jax
-    with pytest.raises(ValueError, match="temperature"):
-        eng.step(sampler="categorical", temperature=0.0,
-                 key=jax.random.PRNGKey(0))  # used to divide by zero
+    eng = rt.LMEngine(cfg, params, slots=1, max_len=16)
     with pytest.raises(TypeError, match="SamplingSpec"):
-        eng.add_request(0, _prompt(40, 4, cfg), sampling={"temperature": 1.0})
+        eng.submit(_prompt(40, 4, cfg), sampling={"temperature": 1.0})
     with pytest.raises(TypeError, match="SamplingSpec"):
-        rt.LMEngine(cfg, params, slots=1, max_len=16).submit(
-            _prompt(40, 4, cfg), sampling=0.7)
+        eng.submit(_prompt(40, 4, cfg), sampling=0.7)
+    assert eng.in_flight == 0  # rejected at submit, before any slot
 
 
 def test_sampled_stream_deterministic_across_engines(smoke):
@@ -313,15 +318,6 @@ def test_sampled_stream_deterministic_across_engines(smoke):
     assert len(outs[0]) == 6
 
 
-def test_categorical_step_api_works_when_valid(smoke):
-    cfg, params = smoke
-    eng = ServeEngine(cfg, params, 1, 16)
-    eng.add_request(0, _prompt(42, 4, cfg))
-    nxt = eng.step(sampler="categorical", temperature=1.3,
-                   key=jax.random.PRNGKey(5))
-    assert nxt is not None and 0 <= int(nxt[0]) < cfg.vocab
-
-
 # -- misc --------------------------------------------------------------------
 
 def test_paging_rejects_unsupported_stacks():
@@ -333,12 +329,11 @@ def test_paging_rejects_unsupported_stacks():
 
 def test_kv_bytes_metric_scales_with_live_blocks(smoke):
     cfg, params = smoke
-    eng = ServeEngine(cfg, params, 2, 64,
+    eng = rt.LMEngine(cfg, params, slots=2, max_len=64, decode_per_step=1,
                       paged=PagedConfig(block_size=8))
-    ref = ServeEngine(cfg, params, 2, 64)
+    ref = rt.LMEngine(cfg, params, slots=2, max_len=64, decode_per_step=1)
     for e in (eng, ref):
-        e.add_request(0, _prompt(43, 5, cfg))
-    eng.step()
-    ref.step()
+        e.submit(_prompt(43, 5, cfg), max_new_tokens=4)
+        e.step()
     # paged reads ceil(len/bs) blocks; contiguous reads slots * max_len
     assert 0 < eng.kv_bytes_touched < ref.kv_bytes_touched

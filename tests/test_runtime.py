@@ -19,7 +19,7 @@ from repro import runtime as rt
 from repro.configs.registry import ARCHS
 from repro.core import factorizer as fz
 from repro.engine.sharding.autotune import retune_slots
-from repro.launch.serve import ServeEngine
+from repro.lm.paging import PagedConfig
 from repro.models import lvrf, nvsa
 from repro.nn import transformer as T
 
@@ -387,12 +387,14 @@ def test_runtime_ewma_drift_triggers_retune(lvrf_setup):
     np.testing.assert_array_equal(got, np.asarray(vals))
 
 
-def test_runtime_mixed_traffic_bit_equal_acceptance(lvrf_setup):
+@pytest.mark.parametrize("paged", [None, PagedConfig()],
+                         ids=["contiguous", "paged"])
+def test_runtime_mixed_traffic_bit_equal_acceptance(lvrf_setup, paged):
     """The ISSUE acceptance bar: one Runtime serves concurrent
     nvsa_abduction + lvrf_rows + lm_decode traffic from its background
     thread; every factorization request is bit-equal to a solo factorize()
     with the same key ACROSS a mid-run EWMA-triggered re-tune, and LM
-    outputs match a solo ServeEngine."""
+    outputs on either KV layout match a solo contiguous LMEngine."""
     spec_l, cfg_l, atoms = lvrf_setup
     cfg_n = nvsa.NVSAConfig()
     spec_n = engine.registry.build("nvsa_abduction", jax.random.PRNGKey(0),
@@ -416,7 +418,8 @@ def test_runtime_mixed_traffic_bit_equal_acceptance(lvrf_setup):
     r.register("nvsa", engine.Engine(spec_n, slots=4))
     r.register("lvrf", lvrf_eng, retune=rt.RetunePolicy(
         threshold=2.0, check_every=1, baseline_rps=1e-3, candidates=(8,)))
-    r.register("lm", rt.LMEngine(cfg_lm, params, slots=2, max_len=32))
+    r.register("lm", rt.LMEngine(cfg_lm, params, slots=2, max_len=32,
+                                 paged=paged))
     with r:
         g_n = r.submit("nvsa", ctx, keys=nkeys)
         g_l = [r.submit("lvrf", good[i], keys=lkeys[i][None])
@@ -446,13 +449,11 @@ def test_runtime_mixed_traffic_bit_equal_acceptance(lvrf_setup):
                                       np.asarray(solo.indices))
         np.testing.assert_array_equal(np.asarray(req_l[i].result["values"][0]),
                                       np.asarray(vals[i]))
-    # LM parity vs a solo ServeEngine decode of the same prompts
+    # LM parity vs a solo LMEngine decode of the same prompts
     for p, req in zip(prompts, req_t):
-        ref = ServeEngine(cfg_lm, params, 1, 32)
-        ref.add_request(0, p)
-        for _ in range(5):
-            ref.step()
-        assert req.result["tokens"] == ref.generated[0][1:6]
+        ref = rt.LMEngine(cfg_lm, params, slots=1, max_len=32)
+        ref.submit(p, max_new_tokens=5)
+        assert req.result["tokens"] == ref.drain()[0].tokens
     # every engine reports through the merged stats path, plus the
     # per-class SLO section (register() reserves the "slo" name)
     st = r.stats()

@@ -29,7 +29,6 @@ from repro import engine
 from repro import runtime as rt
 from repro.configs.registry import ARCHS
 from repro.core import factorizer as fz
-from repro.launch.serve import ServeEngine
 from repro.models import lvrf, nvsa
 from repro.nn import transformer as T
 from repro.runtime import faults as flt
@@ -402,6 +401,14 @@ def test_engine_health_check_flags_only_live_rows(lvrf_setup):
     assert msg is not None and "non-finite" in msg
 
 
+def _solo_lm_tokens(cfg, params, prompt):
+    """The 5 greedy tokens of ``prompt`` decoded alone on a one-slot
+    LMEngine."""
+    ref = rt.LMEngine(cfg, params, slots=1, max_len=32)
+    ref.submit(prompt, max_new_tokens=5)
+    return ref.drain()[0].tokens
+
+
 def test_lm_engine_recover_replays_bit_equal():
     cfg_lm = ARCHS["llama3.2-3b"].smoke()
     params, _ = T.init(jax.random.PRNGKey(0), cfg_lm)
@@ -413,11 +420,8 @@ def test_lm_engine_recover_replays_bit_equal():
     assert eng.recover() == 2 and eng.recoveries_total == 1
     done = {r.id: r for r in eng.drain()}
     for p, rid in zip(prompts, ids):  # greedy decode: bit-equal re-generation
-        ref = ServeEngine(cfg_lm, params, 1, 32)
-        ref.add_request(0, p)
-        for _ in range(5):
-            ref.step()
-        assert done[rid].result["tokens"] == ref.generated[0][1:6]
+        want = _solo_lm_tokens(cfg_lm, params, p)
+        assert done[rid].result["tokens"] == want
 
 
 def test_engine_preempt_replays_bit_equal(lvrf_setup):
@@ -463,11 +467,8 @@ def test_lm_engine_preempt_replays_bit_equal():
     assert eng.preempt(999) == 0
     done = {r.id: r for r in eng.drain()}
     for p, rid in zip(prompts, ids):
-        ref = ServeEngine(cfg_lm, params, 1, 32)
-        ref.add_request(0, p)
-        for _ in range(5):
-            ref.step()
-        assert done[rid].result["tokens"] == ref.generated[0][1:6]
+        want = _solo_lm_tokens(cfg_lm, params, p)
+        assert done[rid].result["tokens"] == want
 
 
 class _FailOnStep:
@@ -547,7 +548,7 @@ def test_chaos_mixed_traffic_every_future_resolves(lvrf_setup):
           FaultError — and the runtime stays serving end to end;
       (b) every factorization result is bit-equal to a solo factorize()
           with the same pinned key, and every LM result matches a solo
-          ServeEngine decode — i.e. replay-recovered trajectories are
+          LMEngine decode — i.e. replay-recovered trajectories are
           indistinguishable from a fault-free run.
     """
     spec_l, cfg_l, atoms = lvrf_setup
@@ -637,9 +638,5 @@ def test_chaos_mixed_traffic_every_future_resolves(lvrf_setup):
         if isinstance(o, flt.InjectedFault):
             lm_rejects += 1  # rejected at submit: structured, not hung
             continue
-        ref = ServeEngine(cfg_lm, params, 1, 32)
-        ref.add_request(0, p)
-        for _ in range(5):
-            ref.step()
-        assert o.result["tokens"] == ref.generated[0][1:6]
+        assert o.result["tokens"] == _solo_lm_tokens(cfg_lm, params, p)
     assert lm_rejects == lm_chaos.injected["submit_reject"]

@@ -100,21 +100,3 @@ def test_grad_clip():
     clipped, norm = optim.clip_by_global_norm(g, 1.0)
     assert float(norm) == pytest.approx(200.0)
     assert float(jnp.linalg.norm(clipped["a"])) == pytest.approx(1.0, rel=1e-4)
-
-
-def test_serve_engine_continuous_batching():
-    from repro.configs.registry import ARCHS
-    from repro.launch.serve import ServeEngine
-    from repro.nn import transformer as T
-    cfg = ARCHS["minicpm-2b"].smoke()
-    params, _ = T.init(jax.random.PRNGKey(0), cfg)
-    eng = ServeEngine(cfg, params, batch_slots=2, max_len=24)
-    prompt = jax.random.randint(jax.random.PRNGKey(1), (4,), 0, cfg.vocab)
-    eng.add_request(0, prompt)
-    for s in range(2):
-        eng.active[s] = True
-        eng.generated[s] = [int(prompt[-1])]
-    for _ in range(6):
-        nxt = eng.step()
-    assert len(eng.generated[0]) == 7
-    assert all(0 <= t < cfg.vocab for t in eng.generated[0])
